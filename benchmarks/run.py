@@ -124,6 +124,9 @@ def main() -> None:
     if unknown:
         parser.error(f"unknown modules: {unknown}")
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     print("name,us_per_call,derived")
     t_start = time.time()
     failures = 0

@@ -56,9 +56,11 @@ def main():
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.relational import QueryService, c, expr as E
     from repro.relational.tpcds import build_tpcds_session, tpcds_queries
 
+    enable_compile_cache()
     sess = build_tpcds_session(scale_rows=args.scale_rows,
                                budget_bytes=1 << 30)
     qs = tpcds_queries(sess)

@@ -26,10 +26,12 @@ def main():
     args = ap.parse_args()
 
     from repro.configs import get_config
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.models.model import init_params
     from repro.serving.engine import ServingEngine
     from repro.serving.request import GenerationRequest
 
+    enable_compile_cache()
     cfg = replace(get_config(args.arch + "-smoke"), n_prefix_tokens=0)
     params = init_params(cfg, 0)
     eng = ServingEngine(cfg, params,

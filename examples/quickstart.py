@@ -77,6 +77,9 @@ def build_catalog(sess: Session, seed: int = 7):
 
 
 def main():
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     # one frozen config instead of the legacy knob sprawl (the old
     # keyword arguments still work as deprecation shims)
     sess = Session.from_config(SessionConfig(

@@ -25,9 +25,11 @@ def main():
 
     from repro.configs import get_config
     from repro.data.pipeline import DataConfig
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.train.optimizer import OptConfig
     from repro.train.trainer import TrainerConfig, train
 
+    enable_compile_cache()
     base = get_config(args.arch + "-smoke")
     cfg = replace(
         base, name=f"{args.arch}-train-demo",

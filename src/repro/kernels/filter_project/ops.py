@@ -195,10 +195,16 @@ def kernel_supports(pred: E.Expr,
         return False
 
 
+def _selected(mask: jnp.ndarray) -> jnp.ndarray:
+    """Selected rows per mask row (int32 even under x64)."""
+    return jnp.sum(mask, axis=-1, dtype=jnp.int32)
+
+
 def filter_mask(columns: Tuple[jnp.ndarray, ...], program: PredProgram,
                 nrows: int, *, block: int = DEFAULT_BLOCK,
                 use_pallas: bool = True, interpret: bool | None = None):
-    """mask+counts via the kernel (padding columns to a block multiple)."""
+    """(mask, count) via the kernel (padding columns to a block
+    multiple); the count is an XLA reduction of the mask."""
     n = columns[0].shape[0]
     padded_n = ((n + block - 1) // block) * block
     if padded_n != n:
@@ -208,15 +214,15 @@ def filter_mask(columns: Tuple[jnp.ndarray, ...], program: PredProgram,
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     if use_pallas:
-        mask, counts = filter_scan(columns, program, nrows, block=block,
-                                   interpret=interpret)
+        mask = filter_scan(columns, program, nrows, block=block,
+                           interpret=interpret)
     else:
-        mask, counts = filter_scan_ref(columns, program, nrows, block)
-    return mask[:n], counts
+        mask = filter_scan_ref(columns, program, nrows)
+    return mask[:n], _selected(mask)
 
 
 _batch_ref = functools.partial(
-    jax.jit, static_argnames=("program", "block"))(filter_scan_batch_ref)
+    jax.jit, static_argnames=("program",))(filter_scan_batch_ref)
 
 
 def filter_mask_batch(columns: Tuple[jnp.ndarray, ...],
@@ -224,7 +230,8 @@ def filter_mask_batch(columns: Tuple[jnp.ndarray, ...],
                       iconsts, fconsts, *, block: int = DEFAULT_BLOCK,
                       use_pallas: bool = True,
                       interpret: bool | None = None):
-    """n-query masks+counts in ONE dispatch over shared columns.
+    """n-query masks (n_q, N) and counts (n_q,) in ONE mask dispatch
+    over shared columns.
 
     ``use_pallas=False`` routes through the jitted XLA oracle — the
     fallback batch path when a program falls off the Pallas route."""
@@ -239,10 +246,8 @@ def filter_mask_batch(columns: Tuple[jnp.ndarray, ...],
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     if use_pallas:
-        mask, counts = filter_scan_batch(columns, program, nrows,
-                                         iconsts, fconsts, block=block,
-                                         interpret=interpret)
+        mask = filter_scan_batch(columns, program, nrows, iconsts,
+                                 fconsts, block=block, interpret=interpret)
     else:
-        mask, counts = _batch_ref(columns, program, nrows, iconsts,
-                                  fconsts, block=block)
-    return mask[:, :n], counts
+        mask = _batch_ref(columns, program, nrows, iconsts, fconsts)
+    return mask[:, :n], _selected(mask)

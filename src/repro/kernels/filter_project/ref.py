@@ -128,35 +128,26 @@ def eval_program(program: PredProgram, cols: Sequence[jnp.ndarray],
 
 
 def filter_scan_ref(columns: Sequence[jnp.ndarray], program: PredProgram,
-                    nrows: int | jnp.ndarray, block: int = 1024
-                    ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Returns (mask bool (N,), per-block selected counts (N//block,))."""
+                    nrows: int | jnp.ndarray) -> jnp.ndarray:
+    """Returns mask bool (N,)."""
     n = columns[0].shape[0]
-    mask = eval_program(program, columns)
-    mask = mask & (jnp.arange(n) < nrows)
-    counts = jnp.sum(mask.reshape(n // block, block).astype(jnp.int32),
-                     axis=1)
-    return mask, counts
+    return eval_program(program, columns) & (jnp.arange(n) < nrows)
 
 
 def filter_scan_batch_ref(columns: Sequence[jnp.ndarray],
                           program: PredProgram, nrows: int | jnp.ndarray,
-                          iconsts: jnp.ndarray, fconsts: jnp.ndarray,
-                          block: int = 1024
-                          ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+                          iconsts: jnp.ndarray, fconsts: jnp.ndarray
+                          ) -> jnp.ndarray:
     """Batched oracle: one pass over the columns evaluates a SLOTTED
     program for every row of the const arrays at once.
 
-    Returns (mask bool (n_q, N), per-block counts (n_q, N//block)).
+    Returns mask bool (n_q, N).
     """
     n = columns[0].shape[0]
     n_q = iconsts.shape[0]
     mask = eval_program(program, columns, iconsts=iconsts,
                         fconsts=fconsts, bshape=(n_q, n))
-    mask = mask & (jnp.arange(n)[None, :] < nrows)
-    counts = jnp.sum(
-        mask.reshape(n_q, n // block, block).astype(jnp.int32), axis=2)
-    return mask, counts
+    return mask & (jnp.arange(n)[None, :] < nrows)
 
 
 def parse_i32_ref(digits: jnp.ndarray) -> jnp.ndarray:

@@ -12,20 +12,8 @@ import jax
 
 
 def _mesh(shape, axes):
-    """Version-portable mesh construction: newer jax wants explicit
-    axis_types (Auto), older jax (< 0.5) has neither AxisType nor the
-    axis_types parameter — fall back progressively."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(axis_type.Auto,) * len(axes))
-    try:
-        return jax.make_mesh(shape, axes)
-    except AttributeError:  # pragma: no cover - very old jax
-        import numpy as np
-
-        devices = np.asarray(jax.devices()[: int(np.prod(shape))])
-        return jax.sharding.Mesh(devices.reshape(shape), axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -123,10 +123,7 @@ def moe_forward_ep(p, x: jnp.ndarray, cfg: ArchConfig, dtype,
     The only cross-shard collective is one psum of the (S_local, d)
     partial outputs over the model axis.
     """
-    try:
-        from jax import shard_map
-    except ImportError:  # older jax
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     b, t, d = x.shape

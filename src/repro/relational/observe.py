@@ -102,6 +102,8 @@ class Telemetry:
         reg.inc("trace.misses", m.trace_misses)
         reg.inc("dispatch.batched", m.batched_dispatches)
         reg.inc("dispatch.batched_queries", m.batched_queries)
+        reg.inc("dispatch.pallas", m.pallas_dispatches)
+        reg.inc("dispatch.xla_slotted", m.xla_slotted_dispatches)
         reg.inc("pid.hits", m.pid_hits)
         reg.inc("pid.pruned_parts", m.pid_pruned_parts)
         reg.inc("pid.records", m.pid_records)

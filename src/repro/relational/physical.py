@@ -113,6 +113,10 @@ class ExecMetrics:
     # window batching: shared dispatches and the queries they covered
     batched_dispatches: int = 0
     batched_queries: int = 0
+    # slotted mask dispatches by route: the Pallas kernel, or the XLA
+    # evaluation of the same program (kernel declined or route off)
+    pallas_dispatches: int = 0
+    xla_slotted_dispatches: int = 0
     # pid bitset pool (PR 8): resident bitsets used by lookups, the
     # partitions they pruned beyond statistics, and new recordings
     pid_hits: int = 0
@@ -333,36 +337,54 @@ def _compact(mask: jnp.ndarray, new_cap: int, *cols):
     return tuple(jnp.take(c, sel, axis=0) for c in cols)
 
 
+# row width of the tiled running count (see _prefix_count)
+_SCAN_TILE = 1024
+
+
+def _prefix_count(mask: jnp.ndarray) -> jnp.ndarray:
+    """Inclusive running count of the set rows of a 1-D mask (int32).
+
+    Long masks are counted as ``(rows, _SCAN_TILE)`` tiles plus a scan
+    of the tile totals: the same values as one flat cumsum, which the
+    v5e compiler takes ~4 s over at 2^22 rows against ~0.3 s for the
+    tiled form (``tests/test_tpu_compile.py`` compiles it)."""
+    x = mask.astype(jnp.int32)
+    n = x.shape[0]
+    if n <= _SCAN_TILE or n % _SCAN_TILE:
+        return jnp.cumsum(x)
+    tiles = jnp.cumsum(x.reshape(n // _SCAN_TILE, _SCAN_TILE), axis=1)
+    before = jnp.cumsum(tiles[:, -1]) - tiles[:, -1]
+    return (tiles + before[:, None]).reshape(n)
+
+
+def _nonzero_rows(mask: jnp.ndarray, size: int) -> jnp.ndarray:
+    """``jnp.nonzero(mask, size=size, fill_value=0)[0]`` as int32: the
+    ascending indices of the first ``size`` set rows, 0 after them.
+
+    Each set row scatters its own index to its rank; jnp.nonzero gets
+    there through two flat cumsums and a bincount, so this form does
+    one scatter and a tiled count (``_prefix_count``) instead."""
+    rank = jnp.where(mask, _prefix_count(mask) - 1, size)
+    rows = jnp.arange(mask.shape[0], dtype=jnp.int32)
+    return jnp.zeros((size,), jnp.int32).at[rank].set(rows, mode="drop")
+
+
 def _compact_nz_impl(mask: jnp.ndarray, new_cap: int, *cols):
     """O(n) compaction via nonzero (vs the argsort in ``_compact``).
 
-    ``nonzero`` returns selected row indices in ascending order — the
-    same live rows, in the same order, as the stable argsort of ~mask;
-    fill rows (beyond the selected count) simply repeat row 0, which is
-    compaction slack every operator already tolerates.  Used on the
-    fused/deferred paths; the plain ``_compact`` is kept as the seed
-    eager behavior.
+    ``_nonzero_rows`` returns selected row indices in ascending order —
+    the same live rows, in the same order, as the stable argsort of
+    ~mask; fill rows (beyond the selected count) simply repeat row 0,
+    which is compaction slack every operator already tolerates.  Used
+    on the fused/deferred paths; the plain ``_compact`` is kept as the
+    seed eager behavior.
     """
-    (sel,) = jnp.nonzero(mask, size=new_cap, fill_value=0)
+    sel = _nonzero_rows(mask, new_cap)
     return tuple(jnp.take(c, sel, axis=0) for c in cols)
 
 
 _compact_nz = partial(jax.jit, static_argnames=("new_cap",))(
     _compact_nz_impl)
-# overflow-recompact variant: donates the mask buffer so the re-dispatch
-# can reuse its device memory (meaningful on tpu/gpu; no-op on cpu,
-# where jax warns, so the call site gates on backend)
-_compact_nz_donated = partial(jax.jit, static_argnames=("new_cap",),
-                              donate_argnums=(0,))(_compact_nz_impl)
-
-_DONATE_OK: Optional[bool] = None
-
-
-def _donate_ok() -> bool:
-    global _DONATE_OK
-    if _DONATE_OK is None:
-        _DONATE_OK = jax.default_backend() in ("tpu", "gpu")
-    return _DONATE_OK
 
 
 def _sort_sentinel(k: jnp.ndarray):
@@ -382,9 +404,14 @@ def _sort_order(key: jnp.ndarray, nrows, asc_sentinel: bool):
 
 @jax.jit
 def _join_build(rk: jnp.ndarray, r_nrows):
+    """Sorted build side plus the [min, max] of its matchable keys
+    (the sentinel never matches, so it is left out of the range)."""
     masked = jnp.where(jnp.arange(rk.shape[0]) < r_nrows, rk, I32_SENTINEL)
     order = jnp.argsort(masked, stable=True)
-    return order, jnp.take(masked, order)
+    live = masked != I32_SENTINEL
+    kmin = jnp.min(jnp.where(live, masked, I32_SENTINEL))
+    kmax = jnp.max(jnp.where(live, masked, np.iinfo(np.int32).min))
+    return order, jnp.take(masked, order), kmin, kmax
 
 
 @jax.jit
@@ -394,6 +421,26 @@ def _join_probe(lk: jnp.ndarray, rk_sorted: jnp.ndarray, l_nrows):
     lo = jnp.searchsorted(rk_sorted, keys, side="left")
     hi = jnp.searchsorted(rk_sorted, keys, side="right")
     m = jnp.where(valid & (keys != I32_SENTINEL), hi - lo, 0)
+    return lo, m, jnp.sum(m)
+
+
+@partial(jax.jit, static_argnames=("span",))
+def _join_probe_dense(lk: jnp.ndarray, rk_sorted: jnp.ndarray, l_nrows,
+                      kmin, kmax, span: int):
+    """``_join_probe`` through a direct-address table over the build
+    keys' range: ``first[j]`` counts build keys below ``kmin + j``, so a
+    probe key's (lo, m) is two gathers from the table instead of a
+    binary search — log2(build) gathers over every probe row, which is
+    what a TPU pays most for.  ``span`` (static) is a power of two
+    covering ``kmax - kmin + 1``; (lo, m) equal ``_join_probe``'s
+    wherever m > 0."""
+    grid = kmin + jnp.arange(span + 1, dtype=jnp.int32)
+    first = jnp.searchsorted(rk_sorted, grid, side="left")
+    valid = jnp.arange(lk.shape[0]) < l_nrows
+    inside = valid & (lk >= kmin) & (lk <= kmax)
+    off = jnp.where(inside, lk - kmin, 0)
+    lo = jnp.take(first, off)
+    m = jnp.where(inside, jnp.take(first, off + 1) - lo, 0)
     return lo, m, jnp.sum(m)
 
 
@@ -433,10 +480,9 @@ def _device_put(arr: np.ndarray, ctx: ExecContext) -> jnp.ndarray:
         if ctx.disk_latency_per_byte:
             time.sleep(arr.nbytes * ctx.disk_latency_per_byte)
         if ctx.sharding is not None and arr.ndim >= 1:
-            try:
-                return jax.device_put(arr, ctx.sharding)
-            except ValueError:
-                pass
+            # a sharding that cannot be applied raises: placing the
+            # array on one device instead would hide the layout change
+            return jax.device_put(arr, ctx.sharding)
         return jnp.asarray(arr)
 
 
@@ -665,8 +711,7 @@ def _est_cap(est: int, upper: int) -> int:
     return max(1, min(cap, next_pow2(max(upper, 1))))
 
 
-def _deferred_dispatch(dispatch, est: int, upper: int, count,
-                       final_dispatch=None):
+def _deferred_dispatch(dispatch, est: int, upper: int, count):
     """The deferred-sync pattern, shared by filter/join/aggregate and
     the fused pipeline: dispatch at the estimate-sized capacity BEFORE
     the host reads the true count, validate, and re-dispatch at the
@@ -682,11 +727,6 @@ def _deferred_dispatch(dispatch, est: int, upper: int, count,
     admitted to the CE cache at its padded nbytes, evicting entries the
     knapsack believed would fit.
 
-    ``final_dispatch``, when given, runs the overflow/tighten re-dispatch
-    instead of ``dispatch`` — the fused path passes a buffer-DONATING
-    compaction there, since at that point the speculative output and the
-    mask are dead and their device memory can be reused.
-
     Returns (dispatch result, int count).
     """
     cap = _est_cap(est, upper)
@@ -694,7 +734,7 @@ def _deferred_dispatch(dispatch, est: int, upper: int, count,
     n = int(count)
     tight = next_pow2(max(n, 1))
     if n > cap or cap > 2 * tight:
-        out = (final_dispatch or dispatch)(tight)
+        out = dispatch(tight)
     return out, n
 
 
@@ -734,8 +774,17 @@ def _exec_join(node: L.Join, left: Table, right: Table,
     # nrows hold stale values (compaction slack) — mask them to the
     # sentinel BEFORE sorting so rk_sorted is genuinely ascending and
     # searchsorted never matches padding.
-    order, rk_sorted = _join_build(rk, jnp.int32(right.nrows))
-    lo, m, total = _join_probe(lk, rk_sorted, jnp.int32(left.nrows))
+    order, rk_sorted, kmin, kmax = _join_build(rk, jnp.int32(right.nrows))
+    # one host read of the build keys' range picks the probe: a table
+    # over the range when it is no longer than the probe side
+    lo_key, hi_key = (int(k) for k in jax.device_get((kmin, kmax)))
+    span = next_pow2(hi_key - lo_key + 1) if hi_key >= lo_key else 0
+    if 0 < span <= lk.shape[0]:
+        lo, m, total = _join_probe_dense(lk, rk_sorted,
+                                         jnp.int32(left.nrows), kmin, kmax,
+                                         span=span)
+    else:
+        lo, m, total = _join_probe(lk, rk_sorted, jnp.int32(left.nrows))
 
     def gather(out_cap: int) -> Dict[str, jnp.ndarray]:
         li, ri = _join_expand(lo, m, out_cap)
@@ -922,7 +971,7 @@ def _union_fn(key, names: Tuple[str, ...], l_cap: int, r_cap: int,
     def f(l_nrows, r_nrows, *cols):
         mask = jnp.concatenate([jnp.arange(l_cap) < l_nrows,
                                 jnp.arange(r_cap) < r_nrows])
-        (sel,) = jnp.nonzero(mask, size=new_cap, fill_value=0)
+        sel = _nonzero_rows(mask, new_cap)
         outs = []
         for lc, rc in zip(cols[:k], cols[k:]):
             merged = jnp.concatenate([lc, rc], axis=0)
@@ -967,6 +1016,10 @@ def _exec_union(left: Table, right: Table, ctx: ExecContext) -> Table:
     return Table(left.schema, cols, total)
 
 
+# column kinds the Pallas filter kernel reads (see _try_pallas_filter)
+_KERNEL_KINDS = ("i32", "f32")
+
+
 def _try_pallas_filter(pred: E.Expr, child: Table):
     """Route a numeric predicate through the fused filter-scan kernel.
     Returns (mask, count) or (None, None) when unsupported (string
@@ -975,16 +1028,17 @@ def _try_pallas_filter(pred: E.Expr, child: Table):
     kernels.filter_project.ops.compile_predicate)."""
     from ..kernels.filter_project.ops import compile_predicate, filter_mask
 
+    # i64 columns stay off the kernel route: Mosaic has no 64-bit lane,
+    # so a predicate reading one raises KeyError here and takes XLA
     numeric = tuple(n for n, t in child.schema.fields
-                    if t.kind in ("i32", "i64", "f32"))
+                    if t.kind in _KERNEL_KINDS)
     try:
         program = compile_predicate(pred, numeric)
     except (ValueError, KeyError):
         return None, None
     cols = tuple(child.columns[n] for n in numeric)
     block = min(2048, child.capacity)
-    mask, counts = filter_mask(cols, program, child.nrows, block=block)
-    return mask, jnp.sum(counts)
+    return filter_mask(cols, program, child.nrows, block=block)
 
 
 # ---------------------------------------------------------------------------
@@ -997,12 +1051,8 @@ def _sharded_mask_fn(key, pred: E.Expr, names: Tuple[str, ...],
     scan runs on every device at once), the count is one psum, and the
     mask comes back row-sharded for the global compaction that follows
     (compaction is data-dependent-shape and stays in XLA/GSPMD)."""
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
-
-    try:
-        from jax import shard_map
-    except ImportError:  # pragma: no cover - older jax
-        from jax.experimental.shard_map import shard_map
 
     def local(nrows, *cols):
         n_local = cols[0].shape[0]
@@ -1015,12 +1065,8 @@ def _sharded_mask_fn(key, pred: E.Expr, names: Tuple[str, ...],
 
     in_specs = (P(),) + tuple(
         P(axis) if nd == 1 else P(axis, None) for nd in ndims)
-    try:
-        fn = shard_map(local, mesh=mesh, in_specs=in_specs,
-                       out_specs=(P(axis), P()), check_vma=False)
-    except TypeError:  # pragma: no cover - pre-check_vma jax
-        fn = shard_map(local, mesh=mesh, in_specs=in_specs,
-                       out_specs=(P(axis), P()), check_rep=False)
+    fn = shard_map(local, mesh=mesh, in_specs=in_specs,
+                   out_specs=(P(axis), P()), check_vma=False)
     return jax.jit(fn)
 
 
@@ -1059,7 +1105,7 @@ def _fused_fn(key, pred: E.Expr, in_names: Tuple[str, ...],
         n = cols[0].shape[0]
         mask = E.eval_expr(pred, columns) & (jnp.arange(n) < nrows)
         count = jnp.sum(mask.astype(jnp.int32))
-        (sel,) = jnp.nonzero(mask, size=new_cap, fill_value=0)
+        sel = _nonzero_rows(mask, new_cap)
         outs = tuple(jnp.take(columns[c], sel, axis=0) for c in out_cols)
         return mask, count, outs
     return jax.jit(f)
@@ -1085,6 +1131,18 @@ def _slot_compile(pred: E.Expr, schema):
     return program, ivals, fvals, names
 
 
+def _kernel_reads(names, schema) -> bool:
+    """Can the Pallas kernel read every one of these columns?"""
+    return all(schema.coltype(n).kind in _KERNEL_KINDS for n in names)
+
+
+def _count_route(ctx: ExecContext, use_pallas: bool) -> None:
+    if use_pallas:
+        ctx.metrics.pallas_dispatches += 1
+    else:
+        ctx.metrics.xla_slotted_dispatches += 1
+
+
 def _slotted_mask(pred: E.Expr, child: Table, ctx: ExecContext,
                   use_pallas: bool):
     """Per-query mask+count through the SLOTTED program route: the
@@ -1098,6 +1156,8 @@ def _slotted_mask(pred: E.Expr, child: Table, ctx: ExecContext,
     if compiled is None:
         return None, None
     program, ivals, fvals, names = compiled
+    if use_pallas and not _kernel_reads(names, child.schema):
+        return None, None
     ic, fc = pack_consts([ivals], [fvals])
     block = min(2048, child.capacity)
     key = ("slotmask", program, names, 1, child.capacity, block,
@@ -1106,7 +1166,8 @@ def _slotted_mask(pred: E.Expr, child: Table, ctx: ExecContext,
         filter_mask_batch, block=block, use_pallas=use_pallas))
     cols = tuple(child.columns[n] for n in names)
     mask, counts = fn(cols, program, jnp.int32(child.nrows), ic, fc)
-    return mask[0], jnp.sum(counts)
+    _count_route(ctx, use_pallas)
+    return mask[0], counts[0]
 
 
 def _fused_est(src, pred: E.Expr, child: Table, est_rows: Optional[int],
@@ -1281,19 +1342,10 @@ def _exec_fused(node: FusedPipeline, ctx: ExecContext) -> Table:
         return _compact_nz(mask, new_cap,
                            *[child.columns[c] for c in node.cols])
 
-    def final_compact(new_cap: int):
-        # overflow/tighten re-dispatch: the mask is dead afterwards, so
-        # donate its buffer where the backend supports donation
-        if _donate_ok():
-            return _compact_nz_donated(
-                mask, new_cap, *[child.columns[c] for c in node.cols])
-        return project_compact(new_cap)
-
     if mask is not None:
         if est is not None:
             outs, count = _deferred_dispatch(
-                project_compact, est, child.capacity, count,
-                final_dispatch=final_compact)
+                project_compact, est, child.capacity, count)
         else:
             count = int(count)
             outs = project_compact(next_pow2(max(count, 1)))
@@ -1310,7 +1362,7 @@ def _exec_fused(node: FusedPipeline, ctx: ExecContext) -> Table:
         tight = next_pow2(max(count, 1))
         if count > new_cap or new_cap > 2 * tight:
             # estimate overflow (or gross overshoot): recompact exactly
-            outs = final_compact(tight)
+            outs = project_compact(tight)
     else:
         # no estimator: two dispatches, but still no intermediate
         # relation — only the output columns are ever compacted
@@ -1449,13 +1501,15 @@ def _prepare_group(members, ctx: ExecContext):
     ic, fc = pack_consts([m.ivals for m in members + fill],
                          [m.fvals for m in members + fill])
     block = min(2048, base.capacity)
-    use_pallas = ctx.use_pallas_filter
+    use_pallas = (ctx.use_pallas_filter
+                  and _kernel_reads(names, base.schema))
     key = ("slotmask", members[0].program, names, n_pad,
            base.capacity, block, use_pallas)
     fn = _shape_cached(ctx, key, lambda: partial(
         filter_mask_batch, block=block, use_pallas=use_pallas))
     mask, counts = fn(cols, members[0].program, jnp.int32(base.nrows),
                       ic, fc)
+    _count_route(ctx, use_pallas)
     ctx.metrics.batched_dispatches += 1
     ctx.metrics.batched_queries += len(members)
     return children, mask, counts
@@ -1470,24 +1524,15 @@ def _finalize_group(members, prep, ctx: ExecContext):
     for q, (m, child) in enumerate(zip(members, children)):
         est = _fused_est(m.src, m.node.pred, child, m.est_rows, ctx)
         mrow = mask[q]
-        crow = jnp.sum(counts[q])
+        crow = counts[q]
 
         def project_compact(new_cap, mrow=mrow, child=child, m=m):
             return _compact_nz(mrow, new_cap,
                                *[child.columns[c] for c in m.node.cols])
 
-        def final_compact(new_cap, mrow=mrow, child=child, m=m,
-                          project_compact=project_compact):
-            if _donate_ok():
-                return _compact_nz_donated(
-                    mrow, new_cap,
-                    *[child.columns[c] for c in m.node.cols])
-            return project_compact(new_cap)
-
         if est is not None:
             cols_out, count = _deferred_dispatch(
-                project_compact, est, child.capacity, crow,
-                final_dispatch=final_compact)
+                project_compact, est, child.capacity, crow)
         else:
             count = int(crow)
             cols_out = project_compact(next_pow2(max(count, 1)))

@@ -1033,9 +1033,12 @@ class QueryService:
         try:
             n_cand, groups = plan_window_batches(
                 [(i, executed[i]) for i in live], ctx)
-        except Exception:
-            # planning must never take the window down — worst case
-            # everything stays on the per-query path
+        except Exception as exc:
+            # planning must never take the window down — everything
+            # stays on the per-query path, and the window report says so
+            ctx.degradations.append(DegradationEvent(
+                query=-1, attempt=1, action="degrade", level="per-query",
+                error=repr(exc), detail={"point": "window_batch_plan"}))
             return done, shared
         if n_cand < 2:
             return done, shared

@@ -107,8 +107,11 @@ def generate_tpcds_catalog(scale_rows: int = 100_000, seed: int = 0
         "ss_wholesale_cost": wholesale,
         "ss_list_price": list_price,
         "ss_sales_price": sales_price,
-        "ss_ext_sales_price": sales_price * qty,
-        "ss_net_profit": (sales_price - wholesale) * qty,
+        # f32 * i32 promotes to f64 in numpy: cast back to the schema's
+        # F32 so host columns hold exactly the values the device sees
+        "ss_ext_sales_price": (sales_price * qty).astype(np.float32),
+        "ss_net_profit": ((sales_price - wholesale) * qty
+                          ).astype(np.float32),
     }
     return {
         "store_sales": (STORE_SALES, n, store_sales),

@@ -6,6 +6,7 @@ the XLA path, with and without deferred synchronization, the fused
 executor's live rows are bit-identical to the seed eager executor's.
 Randomization is seeded numpy (hypothesis is optional in this repo).
 """
+import jax
 import numpy as np
 import pytest
 
@@ -485,8 +486,7 @@ class TestI64Coverage:
 
     @pytest.mark.parametrize("pallas", [False, True])
     def test_i64_filter_matches_oracle(self, pallas):
-        from jax.experimental import enable_x64
-        with enable_x64():
+        with jax.enable_x64(True):
             for case in range(4):
                 sch, st, cols = self._i64_case(600, 6000 + case)
                 thr = int(np.median(cols["big"]))
@@ -509,14 +509,13 @@ class TestI64Coverage:
                     _assert_tables_bit_identical(eager, execute(plan, ctx))
 
     def test_i64_in_list_exact_beyond_2_53(self):
-        from jax.experimental import enable_x64
         # neighbors beyond 2^53 are indistinguishable even in f64 — the
         # membership compare must stay integer-exact
         from repro.relational import I64
         base = (1 << 53) + 2
         vals = np.array([base - 1, base, base + 1, 5], np.int64)
         sch = Schema.of(("big", I64))
-        with enable_x64():
+        with jax.enable_x64(True):
             st, _ = make_storage("t", sch, len(vals), "columnar",
                                  cols={"big": vals})
             plan = (L.scan("t", sch, "columnar")

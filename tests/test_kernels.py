@@ -10,6 +10,7 @@ from hypothesis import given, settings, strategies as st
 from repro.kernels.decode_attention.kernel import decode_attention
 from repro.kernels.decode_attention.ref import decode_ref
 from repro.kernels.filter_project.kernel import filter_scan, parse_i32
+from repro.kernels.filter_project.ops import filter_mask
 from repro.kernels.filter_project.ref import filter_scan_ref, parse_i32_ref
 from repro.kernels.flash_attention.kernel import flash_attention
 from repro.kernels.flash_attention.ref import mha_ref
@@ -36,17 +37,21 @@ class TestFilterScan:
         a = jnp.asarray(RNG.integers(0, 100, n).astype(np.int32))
         b = jnp.asarray(RNG.random(n).astype(np.float32))
         nrows = n - 17
-        m1, c1 = filter_scan((a, b), prog, nrows, block=block,
-                             interpret=True)
-        m2, c2 = filter_scan_ref((a, b), prog, nrows, block)
+        m1 = filter_scan((a, b), prog, nrows, block=block, interpret=True)
+        m2 = filter_scan_ref((a, b), prog, nrows)
         assert bool((m1 == m2).all())
-        assert bool((c1 == c2).all())
+        # the count is the mask's reduction on both routes
+        _, c1 = filter_mask((a, b), prog, nrows, block=block,
+                            interpret=True)
+        _, c2 = filter_mask((a, b), prog, nrows, block=block,
+                            use_pallas=False)
+        assert int(c1) == int(c2) == int(m2.sum())
 
     def test_rows_beyond_nrows_never_match(self):
         n, block = 4096, 1024
         a = jnp.ones((n,), jnp.int32) * 99
-        m, _ = filter_scan((a,), (("gt", 0, 0),), 100, block=block,
-                           interpret=True)
+        m = filter_scan((a,), (("gt", 0, 0),), 100, block=block,
+                        interpret=True)
         assert int(m.sum()) == 100
 
     @settings(max_examples=20, deadline=None)
@@ -54,7 +59,7 @@ class TestFilterScan:
     def test_property_count_matches_numpy(self, nrows, thr):
         n, block = 4096, 1024
         a_np = RNG.integers(0, 100, n).astype(np.int32)
-        m, c = filter_scan((jnp.asarray(a_np),), (("gt", 0, thr),), nrows,
+        m, c = filter_mask((jnp.asarray(a_np),), (("gt", 0, thr),), nrows,
                            block=block, interpret=True)
         expect = int((a_np[:nrows] > thr).sum())
         assert int(m.sum()) == expect == int(c.sum())

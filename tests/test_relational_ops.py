@@ -154,6 +154,47 @@ class TestJoin:
              .join(L.scan("r", sr), "a", "b"))
         assert _run(p, [(stl, lc), (str_, rc)]) == []
 
+    def test_wide_build_keys_take_the_binary_probe(self):
+        # build keys spread far wider than the probe side: no
+        # direct-address table, the searchsorted probe runs
+        (stl, lc), (str_, rc), sl, sr = self._two(dup=False)
+        rc = dict(rc, b=(rc["b"] * 1_000_003 - 7).astype(np.int32))
+        lc = dict(lc, a=rc["b"][lc["a"] % len(rc["b"])])
+        stl, _ = make_storage("l", sl, len(lc["a"]), "columnar", cols=lc)
+        str_, _ = make_storage("r", sr, len(rc["b"]), "columnar", cols=rc)
+        p = L.scan("l", sl).join(L.scan("r", sr), "a", "b")
+        got = _run(p, [(stl, lc), (str_, rc)])
+        assert got and got == _expect(p, [(stl, lc), (str_, rc)])
+
+    @pytest.mark.parametrize("lo_key,width", [
+        (0, 40), (-25, 50), (2**31 - 60, 58), (-2**31, 30)])
+    def test_dense_probe_matches_binary_probe(self, lo_key, width):
+        import jax.numpy as jnp
+
+        from repro.relational.physical import (I32_SENTINEL, _join_build,
+                                               _join_probe,
+                                               _join_probe_dense)
+        from repro.relational.schema import next_pow2
+
+        rng = np.random.default_rng(width)
+        rk = (lo_key + rng.integers(0, width, 64)).astype(np.int32)
+        lk = np.concatenate([
+            (lo_key + rng.integers(-5, width + 5, 500)).clip(
+                -2**31, 2**31 - 1),
+            [I32_SENTINEL, -2**31, 2**31 - 1]]).astype(np.int32)
+        order, rks, kmin, kmax = _join_build(jnp.asarray(rk), jnp.int32(60))
+        span = next_pow2(int(kmax) - int(kmin) + 1)
+        lo1, m1, t1 = _join_probe(jnp.asarray(lk), rks, jnp.int32(490))
+        lo2, m2, t2 = _join_probe_dense(jnp.asarray(lk), rks,
+                                        jnp.int32(490), kmin, kmax,
+                                        span=span)
+        m1, m2 = np.asarray(m1), np.asarray(m2)
+        np.testing.assert_array_equal(m1, m2)
+        hit = m1 > 0
+        assert hit.any() and int(t1) == int(t2)
+        np.testing.assert_array_equal(np.asarray(lo1)[hit],
+                                      np.asarray(lo2)[hit])
+
     def test_join_after_filters_with_stale_padding(self):
         # regression: compaction slack rows must never match (the
         # searchsorted sentinel bug)
@@ -162,6 +203,26 @@ class TestJoin:
              .join(L.scan("r", sr).filter(E.cmp("q", "<", 50)), "a", "b"))
         assert _run(p, [(stl, lc), (str_, rc)]) == _expect(
             p, [(stl, lc), (str_, rc)])
+
+
+class TestCompaction:
+    @pytest.mark.parametrize("n", [5, 1024, 3000, 4096, 1 << 14])
+    @pytest.mark.parametrize("density", [0.0, 0.3, 1.0])
+    @pytest.mark.parametrize("slack", [-7, 0, 9])
+    def test_nonzero_rows_matches_jnp_nonzero(self, n, density, slack):
+        # sizes below, at and above the selected count; masks shorter
+        # than one tile, whole tiles, and a ragged tail
+        import jax.numpy as jnp
+
+        from repro.relational.physical import _nonzero_rows
+
+        rng = np.random.default_rng(n + int(density * 10))
+        mask = rng.random(n) < density
+        size = max(1, int(mask.sum()) + slack)
+        (want,) = jnp.nonzero(jnp.asarray(mask), size=size, fill_value=0)
+        got = _nonzero_rows(jnp.asarray(mask), size)
+        assert got.dtype == jnp.int32
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
 class TestCSVParse:

@@ -188,19 +188,8 @@ class TestGradCompression:
         out = _run("""
             import numpy as np, jax, jax.numpy as jnp
             from jax.sharding import PartitionSpec as P
-            try:
-                from jax import shard_map as _sm
-            except ImportError:
-                from jax.experimental.shard_map import shard_map as _sm
+            from jax import shard_map
             from repro.launch.mesh import make_test_mesh
-
-            def shard_map(f, **kw):
-                # check_vma (new jax) vs check_rep (old jax)
-                kw.pop("check_vma", None)
-                try:
-                    return _sm(f, **kw, check_vma=False)
-                except TypeError:
-                    return _sm(f, **kw, check_rep=False)
 
             mesh = make_test_mesh((4,), ("data",))
             W = jnp.zeros((256, 256))
