@@ -23,6 +23,13 @@ the exception propagates).  Closed root spans accumulate in
 depth-annotated) or Chrome trace-event JSON (complete ``"ph": "X"``
 events, loadable in Perfetto / ``chrome://tracing``).
 
+**On the profiler's clock.**  An enabled tracer also opens a
+``jax.profiler.TraceAnnotation`` named ``repro.<span name>`` for every
+span, so a ``jax.profiler`` trace taken while the program runs holds
+the program's phases on the same clock as the device's operations (a
+gap in the device's work is then named by the phase the host was in).
+Outside a profiler trace an annotation records nothing.
+
 **Zero cost when disabled.**  The default tracer is :data:`NOOP_TRACER`
 whose ``span()`` returns one preallocated singleton no-op context
 manager — no clock reads, no allocations, nothing retained.  Hot paths
@@ -65,7 +72,7 @@ class Span:
     nesting follows the with-statement structure."""
 
     __slots__ = ("name", "t_start", "t_end", "attrs", "children",
-                 "status", "_tracer")
+                 "status", "_tracer", "_annotation")
 
     def __init__(self, tracer: "SpanTracer", name: str,
                  attrs: Dict[str, Any]):
@@ -76,6 +83,7 @@ class Span:
         self.t_end: Optional[float] = None
         self.children: List["Span"] = []
         self.status = "ok"
+        self._annotation = None
 
     @property
     def duration(self) -> Optional[float]:
@@ -94,7 +102,14 @@ class Span:
         if tr._stack:
             tr._stack[-1].children.append(self)
         tr._stack.append(self)
+        self._annotation = tr.annotation(f"repro.{self.name}")
+        self._annotation.__enter__()
         return self
+
+    def _close_annotation(self) -> None:
+        ann, self._annotation = self._annotation, None
+        if ann is not None:
+            ann.__exit__(None, None, None)
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         tr = self._tracer
@@ -102,13 +117,16 @@ class Span:
         if exc_type is not None:
             self.status = "error"
             self.attrs.setdefault("error", repr(exc))
-        # close any child left open by a non-with escape below us, then
-        # pop ourselves: the stack can never wedge on an unwound frame
+        # close any child left open by a non-with escape below us,
+        # innermost first, then pop ourselves: the stack can never wedge
+        # on an unwound frame
         while tr._stack and tr._stack[-1] is not self:
             leaked = tr._stack.pop()
+            leaked._close_annotation()
             if leaked.t_end is None:
                 leaked.t_end = now
                 leaked.status = "error"
+        self._close_annotation()
         self.t_end = now
         if tr._stack and tr._stack[-1] is self:
             tr._stack.pop()
@@ -191,12 +209,18 @@ def _jsonable(v):
 
 
 class SpanTracer:
-    """Collecting tracer with an injectable monotonic clock."""
+    """Collecting tracer with an injectable monotonic clock.  Each span
+    also opens a profiler annotation (``annotation``, JAX's
+    ``TraceAnnotation``), imported here so that a disabled tracer never
+    touches JAX."""
 
     enabled = True
 
     def __init__(self, clock: Callable[[], float] = time.monotonic):
+        from jax.profiler import TraceAnnotation
+
         self.clock = clock
+        self.annotation = TraceAnnotation
         self.finished: List[Span] = []    # closed root spans, in order
         self._stack: List[Span] = []
 

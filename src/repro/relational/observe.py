@@ -107,8 +107,14 @@ class Telemetry:
         reg.inc("pid.hits", m.pid_hits)
         reg.inc("pid.pruned_parts", m.pid_pruned_parts)
         reg.inc("pid.records", m.pid_records)
-        for op, dt in m.op_seconds.items():
-            reg.inc(f"op_seconds.{op}", dt)
+        reg.inc("exec.host_syncs", m.host_syncs)
+        reg.inc("exec.deferred_dispatches",
+                sum(m.deferred_dispatches.values()))
+        reg.inc("exec.redispatches", sum(m.redispatches.values()))
+        for op, n in m.deferred_dispatches.items():
+            reg.inc("exec.deferred_dispatches", n, labels={"op": op})
+            reg.inc("exec.redispatches", m.redispatches.get(op, 0),
+                    labels={"op": op})
 
     # -- export conveniences -------------------------------------------------
     def export_chrome_trace(self, path: Optional[str] = None) -> dict:

@@ -122,10 +122,19 @@ class ExecMetrics:
     pid_hits: int = 0
     pid_pruned_parts: int = 0
     pid_records: int = 0
-    op_seconds: Dict[str, float] = field(default_factory=dict)
+    # device->host reads: each waits for the device to finish what it
+    # reads (see _to_host)
+    host_syncs: int = 0
+    # deferred-sync dispatches by operator, and those thrown away and
+    # run again at the exact size (see _deferred_dispatch)
+    deferred_dispatches: Dict[str, int] = field(default_factory=dict)
+    redispatches: Dict[str, int] = field(default_factory=dict)
 
-    def add_time(self, op: str, dt: float):
-        self.op_seconds[op] = self.op_seconds.get(op, 0.0) + dt
+    def note_dispatch(self, op: str, redispatched: bool) -> None:
+        d = self.deferred_dispatches
+        d[op] = d.get(op, 0) + 1
+        if redispatched:
+            self.redispatches[op] = self.redispatches.get(op, 0) + 1
 
 
 @dataclass
@@ -711,7 +720,23 @@ def _est_cap(est: int, upper: int) -> int:
     return max(1, min(cap, next_pow2(max(upper, 1))))
 
 
-def _deferred_dispatch(dispatch, est: int, upper: int, count):
+def _to_host(ctx: ExecContext, x):
+    """``x`` (device arrays, or a tuple of them) read on the host.  The
+    read waits until the device has computed ``x``: one host sync,
+    counted in ``ExecMetrics.host_syncs`` and spanned as ``exec.sync``."""
+    ctx.metrics.host_syncs += 1
+    with ctx.span("exec.sync"):
+        return jax.device_get(x)
+
+
+def _host_int(ctx: ExecContext, x) -> int:
+    """A row count as a Python int: a device scalar is read on the host
+    (:func:`_to_host`), a host int is taken as it is."""
+    return int(_to_host(ctx, x)) if isinstance(x, jax.Array) else int(x)
+
+
+def _deferred_dispatch(ctx: ExecContext, op: str, dispatch, est: int,
+                       upper: int, count):
     """The deferred-sync pattern, shared by filter/join/aggregate and
     the fused pipeline: dispatch at the estimate-sized capacity BEFORE
     the host reads the true count, validate, and re-dispatch at the
@@ -727,14 +752,17 @@ def _deferred_dispatch(dispatch, est: int, upper: int, count):
     admitted to the CE cache at its padded nbytes, evicting entries the
     knapsack believed would fit.
 
-    Returns (dispatch result, int count).
+    ``op`` names the operator in ``ExecMetrics.deferred_dispatches`` and
+    ``redispatches``.  Returns (dispatch result, int count).
     """
     cap = _est_cap(est, upper)
     out = dispatch(cap)
-    n = int(count)
+    n = _host_int(ctx, count)
     tight = next_pow2(max(n, 1))
-    if n > cap or cap > 2 * tight:
+    redispatch = n > cap or cap > 2 * tight
+    if redispatch:
         out = dispatch(tight)
+    ctx.metrics.note_dispatch(op, redispatch)
     return out, n
 
 
@@ -752,10 +780,10 @@ def _exec_filter(pred: E.Expr, child: Table, ctx: ExecContext) -> Table:
     est = ctx.estimate("filter", pred, child.nrows)
     if est is not None:
         out, count = _deferred_dispatch(
-            lambda cap: _compact_nz(mask, cap, *cols),
+            ctx, "filter", lambda cap: _compact_nz(mask, cap, *cols),
             est, child.capacity, count)
     else:
-        count = int(count)
+        count = _host_int(ctx, count)
         out = _compact(mask, next_pow2(max(count, 1)), *cols)
     ctx.metrics.rows_processed += child.nrows
     return Table(child.schema, dict(zip(names, out)), count)
@@ -777,7 +805,7 @@ def _exec_join(node: L.Join, left: Table, right: Table,
     order, rk_sorted, kmin, kmax = _join_build(rk, jnp.int32(right.nrows))
     # one host read of the build keys' range picks the probe: a table
     # over the range when it is no longer than the probe side
-    lo_key, hi_key = (int(k) for k in jax.device_get((kmin, kmax)))
+    lo_key, hi_key = (int(k) for k in _to_host(ctx, (kmin, kmax)))
     span = next_pow2(hi_key - lo_key + 1) if hi_key >= lo_key else 0
     if 0 < span <= lk.shape[0]:
         lo, m, total = _join_probe_dense(lk, rk_sorted,
@@ -803,9 +831,10 @@ def _exec_join(node: L.Join, left: Table, right: Table,
         # with no stats) must not allocate |L|x|R|-sized arrays; a true
         # output beyond the bound just takes the overflow re-gather
         upper = 4 * max(left.nrows, right.nrows, 1)
-        cols, total = _deferred_dispatch(gather, est, upper, total)
+        cols, total = _deferred_dispatch(ctx, "join", gather, est, upper,
+                                         total)
     else:
-        total = int(total)
+        total = _host_int(ctx, total)
         cols = gather(next_pow2(max(total, 1)))
     ctx.metrics.rows_processed += left.nrows + right.nrows
     return Table(left.schema.concat(right.schema), cols, total)
@@ -889,9 +918,9 @@ def _exec_aggregate(node: L.Aggregate, child: Table,
         # group ids beyond the capacity are scatter-dropped, so an
         # underestimate only triggers the overflow re-reduce
         (outs, first), n_groups = _deferred_dispatch(
-            run_reduce, est, child.nrows, n_groups)
+            ctx, "aggregate", run_reduce, est, child.nrows, n_groups)
     else:
-        n_groups = int(n_groups)
+        n_groups = _host_int(ctx, n_groups)
         outs, first = run_reduce(next_pow2(max(n_groups, 1)))
 
     cols: Dict[str, jnp.ndarray] = {}
@@ -944,8 +973,8 @@ def _exec_sort(node: L.Sort, child: Table, ctx: ExecContext) -> Table:
             return fn(jnp.int32(child.nrows),
                       *[child.columns[n] for n in names])
 
-        outs, _ = _deferred_dispatch(dispatch, est, child.capacity,
-                                     child.nrows)
+        outs, _ = _deferred_dispatch(ctx, "sort", dispatch, est,
+                                     child.capacity, child.nrows)
         return Table(child.schema, dict(zip(names, outs)), child.nrows)
 
     # seed eager path: full-capacity order, one gather per column
@@ -998,7 +1027,8 @@ def _exec_union(left: Table, right: Table, ctx: ExecContext) -> Table:
                       *[right.columns[n] for n in names])
 
         outs, total = _deferred_dispatch(
-            dispatch, est, left.capacity + right.capacity, total)
+            ctx, "union", dispatch, est, left.capacity + right.capacity,
+            total)
         return Table(left.schema, dict(zip(names, outs)), total)
 
     # seed eager path: exact-sized per-column argsort compaction
@@ -1278,7 +1308,7 @@ def _pid_record(ctx: ExecContext, pid_scan, pred: E.Expr, mask,
         key = E.canonical(pred)
         if pool.contains(table_name, key):
             return
-        host = np.asarray(mask)[:nrows]
+        host = _to_host(ctx, mask)[:nrows]
         present = pid_presence_from_mask(host, info, parts)
         pool.record(table_name, key, pred, info.n_partitions, present)
         ctx.metrics.pid_records += 1
@@ -1345,9 +1375,9 @@ def _exec_fused(node: FusedPipeline, ctx: ExecContext) -> Table:
     if mask is not None:
         if est is not None:
             outs, count = _deferred_dispatch(
-                project_compact, est, child.capacity, count)
+                ctx, "project", project_compact, est, child.capacity, count)
         else:
-            count = int(count)
+            count = _host_int(ctx, count)
             outs = project_compact(next_pow2(max(count, 1)))
     elif est is not None:
         # single dispatch: mask, count and the projected compaction all
@@ -1358,18 +1388,20 @@ def _exec_fused(node: FusedPipeline, ctx: ExecContext) -> Table:
         fn = _cached(key, lambda: _fused_fn(key, pred, in_names,
                                             node.cols, new_cap))
         mask, count, outs = fn(jnp.int32(child.nrows), *in_cols)
-        count = int(count)
+        count = _host_int(ctx, count)
         tight = next_pow2(max(count, 1))
-        if count > new_cap or new_cap > 2 * tight:
+        redispatch = count > new_cap or new_cap > 2 * tight
+        if redispatch:
             # estimate overflow (or gross overshoot): recompact exactly
             outs = project_compact(tight)
+        ctx.metrics.note_dispatch("project", redispatch)
     else:
         # no estimator: two dispatches, but still no intermediate
         # relation — only the output columns are ever compacted
         key = ("mask", E.canonical(pred), in_names, child.capacity)
         fn = _cached(key, lambda: _pred_mask_fn(key, pred, in_names))
         mask, count = fn(jnp.int32(child.nrows), *in_cols)
-        count = int(count)
+        count = _host_int(ctx, count)
         outs = project_compact(next_pow2(max(count, 1)))
 
     _pid_record(ctx, pid_scan, pred, mask, child.nrows)
@@ -1532,9 +1564,9 @@ def _finalize_group(members, prep, ctx: ExecContext):
 
         if est is not None:
             cols_out, count = _deferred_dispatch(
-                project_compact, est, child.capacity, crow)
+                ctx, "project", project_compact, est, child.capacity, crow)
         else:
-            count = int(crow)
+            count = _host_int(ctx, crow)
             cols_out = project_compact(next_pow2(max(count, 1)))
         _pid_record(ctx, m.pid_scan, m.node.pred, mrow, child.nrows)
         ctx.metrics.rows_processed += child.nrows
@@ -1580,9 +1612,7 @@ def execute_window_batched(groups, ctx: ExecContext):
                 for m in g:
                     failures[m.pos] = exc
                 continue
-            dt = dt0 + (time.perf_counter() - t0)
-            ctx.metrics.add_time("fused", dt)
-            per = dt / len(g)
+            per = (dt0 + time.perf_counter() - t0) / len(g)
             for m, t in zip(g, outs):
                 results[m.pos] = t
                 seconds[m.pos] = per
@@ -1603,7 +1633,6 @@ def execute(node: L.Node, ctx: ExecContext) -> Table:
 
 
 def _exec(node: L.Node, ctx: ExecContext, req) -> Table:
-    t0 = time.perf_counter()
     if isinstance(node, FusedPipeline):
         out = _exec_fused(node, ctx)
     elif isinstance(node, L.Scan):
@@ -1642,9 +1671,6 @@ def _exec(node: L.Node, ctx: ExecContext, req) -> Table:
         out = _materialize_cache(node, ctx, req)
     else:
         raise TypeError(type(node))
-    jax.block_until_ready(list(out.columns.values()))
-    ctx.metrics.add_time(node.label.split(":")[0],
-                         time.perf_counter() - t0)
     return out
 
 
@@ -1770,6 +1796,9 @@ def _materialize_cache(node: L.Cache, ctx: ExecContext, req) -> Table:
         t0 = time.perf_counter()
         with ctx.span("ce.materialize", psi=node.psi):
             table = _exec(node.child, ctx, req)
+            # the calibration sample times the materialization to the
+            # device's end, not to the last dispatch
+            jax.block_until_ready(list(table.columns.values()))
             ctx.check_fault("ce_admission", key=node.psi)
             ctx.cache.put(node.psi, table, nbytes=table.nbytes,
                           est_bytes=table.logical_nbytes,

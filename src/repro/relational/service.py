@@ -670,6 +670,12 @@ class QueryService:
         with self._span("window", window=window,
                         n_queries=len(handles)) as wsp:
             try:
+                if wsp is not NOOP_SPAN:
+                    # queue wait: each query's time from submit to the
+                    # start of its window's work, summed over the window
+                    start = self._clock()
+                    wsp.set(wait_s=sum(start - h._t_submit
+                                       for h in handles))
                 batch = self._run_window_inner(
                     handles, window, mqo=mqo, k=k,
                     budget_bytes=budget_bytes,

@@ -383,3 +383,199 @@ class TestMetricsReport:
             inj["invocations"]["scan_h2d"]
         rep = svc.metrics_report()
         assert rep["faults"] == inj
+
+
+# ---------------------------------------------------------------------------
+# spans on the profiler's clock
+# ---------------------------------------------------------------------------
+class _RecordingAnnotation:
+    """Stands in for ``jax.profiler.TraceAnnotation``: logs each enter
+    and exit by name."""
+
+    log: list = []
+
+    def __init__(self, name):
+        self.name = name
+
+    def __enter__(self):
+        self.log.append(("enter", self.name))
+        return self
+
+    def __exit__(self, *exc):
+        self.log.append(("exit", self.name))
+        return False
+
+
+class TestProfilerAnnotations:
+    def test_each_span_opens_a_repro_annotation(self, monkeypatch):
+        monkeypatch.setattr(_RecordingAnnotation, "log", [])
+        tr = SpanTracer()
+        tr.annotation = _RecordingAnnotation
+        with tr.span("window"):
+            with tr.span("mqo.solve"):
+                pass
+        assert _RecordingAnnotation.log == [
+            ("enter", "repro.window"), ("enter", "repro.mqo.solve"),
+            ("exit", "repro.mqo.solve"), ("exit", "repro.window")]
+
+    def test_leaked_children_close_innermost_first(self, monkeypatch):
+        monkeypatch.setattr(_RecordingAnnotation, "log", [])
+        tr = SpanTracer()
+        tr.annotation = _RecordingAnnotation
+        with tr.span("parent"):
+            tr.span("child").__enter__()        # never exited
+            tr.span("grandchild").__enter__()   # never exited
+        exits = [n for kind, n in _RecordingAnnotation.log
+                 if kind == "exit"]
+        assert exits == ["repro.grandchild", "repro.child",
+                         "repro.parent"]
+        assert tr._stack == []
+
+    def test_tracing_off_builds_no_annotation(self, monkeypatch):
+        import jax
+
+        class Refused:
+            def __init__(self, *a, **k):
+                raise AssertionError("an annotation was built")
+
+        monkeypatch.setattr(jax.profiler, "TraceAnnotation", Refused)
+        sess = _mk_session()
+        svc = QueryService(sess, max_batch=3)
+        handles = [svc.submit(q) for q in _recurring(sess)]
+        assert all(h.done and not h.failed for h in handles)
+        assert sess.telemetry().tracer is NOOP_TRACER
+        # the patch is the one an enabled tracer would use
+        with pytest.raises(AssertionError, match="annotation was built"):
+            with sess.enable_tracing().span("window"):
+                pass
+
+
+# ---------------------------------------------------------------------------
+# the window's queue wait, host syncs and re-dispatches
+# ---------------------------------------------------------------------------
+class _Clock:
+    def __init__(self):
+        self.now = 0.0
+
+    def __call__(self):
+        return self.now
+
+
+class TestQueueWait:
+    def test_window_span_sums_each_querys_wait(self):
+        sess = _mk_session()
+        sess.enable_tracing()
+        clock = _Clock()
+        svc = QueryService(sess, max_batch=3, clock=clock)
+        for t, q in zip((1.0, 2.5, 4.0), _recurring(sess)):
+            clock.now = t
+            svc.submit(q)            # the third fills and closes the window
+        (window,) = [sp for sp in _all_spans(sess.telemetry().tracer)
+                     if sp.name == "window"]
+        assert window.attrs["n_queries"] == 3
+        assert window.attrs["wait_s"] == pytest.approx(3.0 + 1.5 + 0.0)
+
+    def test_untraced_window_reads_no_clock_for_the_wait(self):
+        sess = _mk_session()
+        reads = [0]
+
+        def clock():
+            reads[0] += 1
+            return 0.0
+
+        svc = QueryService(sess, max_batch=3, clock=clock)
+        for q in _recurring(sess):
+            svc.submit(q)
+        traced_sess = _mk_session()
+        traced_sess.enable_tracing()
+        traced_reads = [0]
+
+        def traced_clock():
+            traced_reads[0] += 1
+            return 0.0
+
+        svc = QueryService(traced_sess, max_batch=3, clock=traced_clock)
+        for q in _recurring(traced_sess):
+            svc.submit(q)
+        assert traced_reads[0] == reads[0] + 1
+
+
+class _FixedEstimate:
+    """A cost model whose filter estimate is a fixed row count."""
+
+    def __init__(self, rows):
+        self.rows = rows
+
+    def filter_estimate(self, pred, nrows):
+        return self.rows
+
+
+class TestHostSyncsAndRedispatches:
+    @pytest.mark.parametrize("fuse,op", [(False, "filter"),
+                                         (True, "project")])
+    @pytest.mark.parametrize("overflow", [True, False])
+    def test_filter_counts(self, fuse, op, overflow):
+        from repro.relational import ExecContext
+        from repro.relational.physical import execute
+
+        rng = np.random.default_rng(3)
+        cols = {c: rng.integers(0, 100, NROWS).astype(np.int32)
+                for c in ("a", "b", "c")}
+        st, _ = make_storage("t", S, NROWS, "columnar", cols=cols)
+        want = int((cols["a"] > 10).sum())
+        plan = (L.scan("t", S, "columnar")
+                .filter(E.cmp("a", ">", 10)).project("a", "b"))
+        ctx = ExecContext(catalog={"t": st}, fuse=fuse,
+                          cost_model=_FixedEstimate(1 if overflow else want))
+        out = execute(plan, ctx)
+        assert out.nrows == want
+        m = ctx.metrics
+        assert m.host_syncs == 1                 # the one count read
+        assert m.deferred_dispatches == {op: 1}
+        assert m.redispatches == ({op: 1} if overflow else {})
+
+        tel = Telemetry()
+        tel.absorb_exec_metrics(m)
+        reg = tel.registry
+        assert reg.value("exec.host_syncs") == 1
+        assert reg.value("exec.deferred_dispatches") == 1
+        assert reg.value("exec.redispatches") == int(overflow)
+        assert reg.value("exec.deferred_dispatches",
+                         labels={"op": op}) == 1
+        assert reg.value("exec.redispatches",
+                         labels={"op": op}) == int(overflow)
+        assert [lab for lab, _ in reg.series("exec.redispatches")] == [
+            {"op": op}]
+
+    def test_window_counters_reach_the_registry(self):
+        sess = _mk_session()
+        svc = QueryService(sess, max_batch=3)
+        for q in _recurring(sess):
+            svc.submit(q)
+        counters = sess.metrics_report()["registry"]["counters"]
+        assert counters["exec.host_syncs"] >= 1
+        assert counters["exec.deferred_dispatches"] >= 1
+        assert "exec.redispatches" in counters
+
+
+class TestNoPerOperatorBarrier:
+    def test_op_seconds_is_gone(self):
+        import pathlib
+
+        from repro.relational import ExecMetrics, physical
+
+        assert not hasattr(ExecMetrics(), "op_seconds")
+        assert not hasattr(ExecMetrics, "add_time")
+        src = pathlib.Path(physical.__file__).parents[1]
+        assert not [p for p in src.rglob("*.py")
+                    if "op_seconds" in p.read_text()]
+        sess, _ = TestMetricsReport()._warm_service()
+        counters = sess.metrics_report()["registry"]["counters"]
+        assert not [k for k in counters if k.startswith("op_seconds")]
+
+    def test_exec_holds_no_device_barrier(self):
+        import inspect
+
+        from repro.relational import physical
+
+        assert "block_until_ready" not in inspect.getsource(physical._exec)
