@@ -5,8 +5,9 @@ Runs the cell as ``chipbench/run.py --trace 0`` does and prints, as the
 last line, one JSON object: the growth of the program's ``exec.*``
 registry counters across the measured window (``exec.host_syncs``,
 ``exec.deferred_dispatches`` and ``exec.redispatches``, the last two
-also per operator as ``{op=...}``), the queries answered in it, and the
-run's ``correct`` verdict.
+also per operator as ``{op=...}``, and ``exec.joins`` by
+``{path=unique|expand}``), the queries answered in it, the run's
+``correct`` verdict and its end-to-end ``metrics``.
 
 Run:  python benchmarks/exec_counters.py --workload sf1-dashboard --seed 7
 """
@@ -51,7 +52,7 @@ def main(argv=None) -> int:
     print(json.dumps({"workload": args.workload, "seed": args.seed,
                       "correct": result["correct"],
                       "answered": result["attempted"] - result["failed"],
-                      "counters": grown}))
+                      "counters": grown, "metrics": result["metrics"]}))
     return 0
 
 

@@ -115,6 +115,10 @@ class Telemetry:
             reg.inc("exec.deferred_dispatches", n, labels={"op": op})
             reg.inc("exec.redispatches", m.redispatches.get(op, 0),
                     labels={"op": op})
+        reg.inc("exec.joins", sum(m.joins.values()))
+        for path in ("unique", "expand"):
+            reg.inc("exec.joins", m.joins.get(path, 0),
+                    labels={"path": path})
 
     # -- export conveniences -------------------------------------------------
     def export_chrome_trace(self, path: Optional[str] = None) -> dict:

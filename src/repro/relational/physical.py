@@ -129,6 +129,12 @@ class ExecMetrics:
     # run again at the exact size (see _deferred_dispatch)
     deferred_dispatches: Dict[str, int] = field(default_factory=dict)
     redispatches: Dict[str, int] = field(default_factory=dict)
+    # joins by path (see _exec_join): "unique" build keys gather the
+    # matched probe rows, "expand" repeats a probe row per match
+    joins: Dict[str, int] = field(default_factory=dict)
+
+    def note_join(self, path: str) -> None:
+        self.joins[path] = self.joins.get(path, 0) + 1
 
     def note_dispatch(self, op: str, redispatched: bool) -> None:
         d = self.deferred_dispatches
@@ -346,50 +352,120 @@ def _compact(mask: jnp.ndarray, new_cap: int, *cols):
     return tuple(jnp.take(c, sel, axis=0) for c in cols)
 
 
-# row width of the tiled running count (see _prefix_count)
-_SCAN_TILE = 1024
+def _selection_keys(mask: jnp.ndarray) -> jnp.ndarray:
+    """One int32 key per row of a 1-D mask: a set row keys as itself,
+    any other row as itself plus ``n``.  Sorted, the set rows come
+    first, in ascending order; every key is unique, so the sort need
+    not be stable (at 2^25 rows a stable sort takes the v5e compiler
+    about five times as long)."""
+    n = mask.shape[0]
+    assert n <= 1 << 30, "selection keys must fit int32"
+    rows = jnp.arange(n, dtype=jnp.int32)
+    return jnp.where(mask, rows, rows + n)
 
 
-def _prefix_count(mask: jnp.ndarray) -> jnp.ndarray:
-    """Inclusive running count of the set rows of a 1-D mask (int32).
-
-    Long masks are counted as ``(rows, _SCAN_TILE)`` tiles plus a scan
-    of the tile totals: the same values as one flat cumsum, which the
-    v5e compiler takes ~4 s over at 2^22 rows against ~0.3 s for the
-    tiled form (``tests/test_tpu_compile.py`` compiles it)."""
-    x = mask.astype(jnp.int32)
-    n = x.shape[0]
-    if n <= _SCAN_TILE or n % _SCAN_TILE:
-        return jnp.cumsum(x)
-    tiles = jnp.cumsum(x.reshape(n // _SCAN_TILE, _SCAN_TILE), axis=1)
-    before = jnp.cumsum(tiles[:, -1]) - tiles[:, -1]
-    return (tiles + before[:, None]).reshape(n)
+def _carries(n: int, cap: int) -> bool:
+    """Whether a compaction of ``n`` rows to ``cap`` sorts its columns
+    along with the selection keys, or sorts the keys alone and gathers
+    each output row through them.  On one v5e chip a gathered element
+    cost 25-33 ns and a column carried through the sort about 1 ns a
+    row (``benchmarks/probe_ops.py`` measures both), so the sort carries
+    from ``cap >= n/16``; below that the gather is cheaper."""
+    return 16 * cap >= n
 
 
-def _nonzero_rows(mask: jnp.ndarray, size: int) -> jnp.ndarray:
-    """``jnp.nonzero(mask, size=size, fill_value=0)[0]`` as int32: the
-    ascending indices of the first ``size`` set rows, 0 after them.
+def _carriable(c: jnp.ndarray) -> bool:
+    """A column the sort can carry: one 4-byte value per row."""
+    return c.ndim == 1 and c.dtype.itemsize == 4
 
-    Each set row scatters its own index to its rank; jnp.nonzero gets
-    there through two flat cumsums and a bincount, so this form does
-    one scatter and a tiled count (``_prefix_count``) instead."""
-    rank = jnp.where(mask, _prefix_count(mask) - 1, size)
-    rows = jnp.arange(mask.shape[0], dtype=jnp.int32)
-    return jnp.zeros((size,), jnp.int32).at[rank].set(rows, mode="drop")
+
+def _sort_selection(mask: jnp.ndarray, cols, carry: bool):
+    """``(keys, carried)``: the sorted selection keys of ``mask`` and,
+    with ``carry``, every carriable column sorted along with them."""
+    if not carry:
+        return jax.lax.sort(_selection_keys(mask), is_stable=False), ()
+    out = jax.lax.sort((_selection_keys(mask),)
+                       + tuple(c for c in cols if _carriable(c)),
+                       num_keys=1, is_stable=False)
+    return out[0], tuple(out[1:])
+
+
+def _head_selected(keys: jnp.ndarray, carried, cols, size: int):
+    """The first ``size`` selected rows of every column, in ascending
+    row order, row 0's value after them: a carried column is sliced
+    from its sorted copy, any other gathered through the keys."""
+    n = keys.shape[0]
+    if size > n:
+        keys = jnp.concatenate([keys, jnp.full((size - n,), n, jnp.int32)])
+        carried = tuple(jnp.concatenate([s, jnp.zeros((size - n,), s.dtype)])
+                        for s in carried)
+    head = keys[:size]
+    live = head < n
+    sel = jnp.where(live, head, 0)
+    outs, sorted_cols = [], iter(carried)
+    for c in cols:
+        if carried and _carriable(c):
+            outs.append(jnp.where(live, next(sorted_cols)[:size], c[0]))
+        else:
+            outs.append(jnp.take(c, sel, axis=0))
+    return tuple(outs)
+
+
+@partial(jax.jit, static_argnames=("carry",))
+def _select_rows(flags: jnp.ndarray, *cols, carry: bool = False):
+    """``_sort_selection`` of the rows where ``flags`` (a mask, or
+    per-row match counts) is nonzero, as its own program: the n-sized
+    part of a compaction, dispatched once before the output is sized."""
+    return _sort_selection(flags != 0, cols, carry)
+
+
+@partial(jax.jit, static_argnames=("new_cap",))
+def _take_selected(keys: jnp.ndarray, carried, new_cap: int, *cols):
+    """The cap-sized part of a compaction (``_head_selected``)."""
+    return _head_selected(keys, carried, cols, new_cap)
+
+
+class _Selection:
+    """A compaction of ``cols`` to the set rows of ``mask`` that may be
+    sized more than once (see ``_deferred_dispatch``): the sort runs at
+    most once per route (``_carries`` of each size asked for), and each
+    size only slices and gathers.  ``keys``/``carried`` may come from a
+    program that already sorted."""
+
+    def __init__(self, mask, cols, keys=None, carried=()):
+        self.mask, self.cols = mask, tuple(cols)
+        self.keys, self.carried = keys, tuple(carried)
+
+    def sort_for(self, new_cap: int):
+        """``(keys, carried)`` for a compaction to ``new_cap``."""
+        carry = (_carries(self.mask.shape[0], new_cap)
+                 and any(_carriable(c) for c in self.cols))
+        if carry and not self.carried:
+            self.keys, self.carried = _select_rows(self.mask, *self.cols,
+                                                   carry=True)
+        elif self.keys is None:
+            self.keys, _ = _select_rows(self.mask)
+        return self.keys, self.carried
+
+    def __call__(self, new_cap: int):
+        return _take_selected(*self.sort_for(new_cap), new_cap, *self.cols)
 
 
 def _compact_nz_impl(mask: jnp.ndarray, new_cap: int, *cols):
-    """O(n) compaction via nonzero (vs the argsort in ``_compact``).
+    """Compaction through one sort of unique keys (vs the stable
+    two-operand argsort in ``_compact``), carrying the columns through
+    the sort or gathering them after it as ``_carries`` says.
 
-    ``_nonzero_rows`` returns selected row indices in ascending order —
-    the same live rows, in the same order, as the stable argsort of
-    ~mask; fill rows (beyond the selected count) simply repeat row 0,
-    which is compaction slack every operator already tolerates.  Used
-    on the fused/deferred paths; the plain ``_compact`` is kept as the
-    seed eager behavior.
+    Rows come out in ascending order — the same live rows, in the same
+    order, as the stable argsort of ~mask; fill rows (beyond the
+    selected count) repeat row 0, which is compaction slack every
+    operator already tolerates.  Used where the output is sized once; a
+    deferred-sync dispatch goes through ``_Selection``.  The plain
+    ``_compact`` is kept as the seed eager behavior.
     """
-    sel = _nonzero_rows(mask, new_cap)
-    return tuple(jnp.take(c, sel, axis=0) for c in cols)
+    keys, carried = _sort_selection(mask, cols,
+                                    _carries(mask.shape[0], new_cap))
+    return _head_selected(keys, carried, cols, new_cap)
 
 
 _compact_nz = partial(jax.jit, static_argnames=("new_cap",))(
@@ -413,14 +489,18 @@ def _sort_order(key: jnp.ndarray, nrows, asc_sentinel: bool):
 
 @jax.jit
 def _join_build(rk: jnp.ndarray, r_nrows):
-    """Sorted build side plus the [min, max] of its matchable keys
-    (the sentinel never matches, so it is left out of the range)."""
+    """Sorted build side, the [min, max] of its matchable keys (the
+    sentinel never matches, so it is left out of the range), and
+    whether two live build rows share a key."""
     masked = jnp.where(jnp.arange(rk.shape[0]) < r_nrows, rk, I32_SENTINEL)
     order = jnp.argsort(masked, stable=True)
     live = masked != I32_SENTINEL
     kmin = jnp.min(jnp.where(live, masked, I32_SENTINEL))
     kmax = jnp.max(jnp.where(live, masked, np.iinfo(np.int32).min))
-    return order, jnp.take(masked, order), kmin, kmax
+    rk_sorted = jnp.take(masked, order)
+    dup = jnp.any((rk_sorted[1:] == rk_sorted[:-1])
+                  & (rk_sorted[1:] != I32_SENTINEL))
+    return order, rk_sorted, kmin, kmax, dup
 
 
 @jax.jit
@@ -461,6 +541,21 @@ def _join_expand(lo, m, out_cap):
     inner = jnp.arange(out_cap) - jnp.take(starts, li)
     ri = jnp.take(lo, li) + inner
     return li, ri
+
+
+@partial(jax.jit, static_argnames=("out_cap", "n_sel"))
+def _join_gather_unique(keys, carried, order, out_cap: int, n_sel: int,
+                        *cols):
+    """A join's output columns when no two build rows share a key: each
+    probe row matches at most one build row, so the output rows are the
+    matched probe rows in order (``keys``/``carried`` from
+    ``_select_rows`` of the match counts), each with the build row at
+    its ``lo``.  ``cols`` are ``lo`` and the probe columns (``n_sel`` in
+    all, in the selection), then the build columns."""
+    lo, *left = _head_selected(keys, carried, cols[:n_sel], out_cap)
+    ri = jnp.take(order, lo)
+    return (tuple(left)
+            + tuple(jnp.take(c, ri, axis=0) for c in cols[n_sel:]))
 
 
 @jax.jit
@@ -779,9 +874,9 @@ def _exec_filter(pred: E.Expr, child: Table, ctx: ExecContext) -> Table:
     cols = [child.columns[n] for n in names]
     est = ctx.estimate("filter", pred, child.nrows)
     if est is not None:
-        out, count = _deferred_dispatch(
-            ctx, "filter", lambda cap: _compact_nz(mask, cap, *cols),
-            est, child.capacity, count)
+        out, count = _deferred_dispatch(ctx, "filter",
+                                        _Selection(mask, cols), est,
+                                        child.capacity, count)
     else:
         count = _host_int(ctx, count)
         out = _compact(mask, next_pow2(max(count, 1)), *cols)
@@ -802,10 +897,13 @@ def _exec_join(node: L.Join, left: Table, right: Table,
     # nrows hold stale values (compaction slack) — mask them to the
     # sentinel BEFORE sorting so rk_sorted is genuinely ascending and
     # searchsorted never matches padding.
-    order, rk_sorted, kmin, kmax = _join_build(rk, jnp.int32(right.nrows))
-    # one host read of the build keys' range picks the probe: a table
-    # over the range when it is no longer than the probe side
-    lo_key, hi_key = (int(k) for k in _to_host(ctx, (kmin, kmax)))
+    order, rk_sorted, kmin, kmax, dup = _join_build(
+        rk, jnp.int32(right.nrows))
+    # one host read of the build keys' range and uniqueness picks the
+    # probe (a table over the range when it is no longer than the probe
+    # side) and the expansion
+    lo_key, hi_key, dup = _to_host(ctx, (kmin, kmax, dup))
+    lo_key, hi_key = int(lo_key), int(hi_key)
     span = next_pow2(hi_key - lo_key + 1) if hi_key >= lo_key else 0
     if 0 < span <= lk.shape[0]:
         lo, m, total = _join_probe_dense(lk, rk_sorted,
@@ -814,23 +912,40 @@ def _exec_join(node: L.Join, left: Table, right: Table,
     else:
         lo, m, total = _join_probe(lk, rk_sorted, jnp.int32(left.nrows))
 
-    def gather(out_cap: int) -> Dict[str, jnp.ndarray]:
-        li, ri = _join_expand(lo, m, out_cap)
-        out: Dict[str, jnp.ndarray] = {}
-        for n in left.schema.names:
-            out[n] = jnp.take(left.columns[n], li, axis=0)
-        for n in right.schema.names:
-            src = jnp.take(right.columns[n], order, axis=0)
-            out[n] = jnp.take(src, ri, axis=0)
-        return out
+    if dup:
+        def gather(out_cap: int) -> Dict[str, jnp.ndarray]:
+            li, ri = _join_expand(lo, m, out_cap)
+            out: Dict[str, jnp.ndarray] = {}
+            for n in left.schema.names:
+                out[n] = jnp.take(left.columns[n], li, axis=0)
+            for n in right.schema.names:
+                src = jnp.take(right.columns[n], order, axis=0)
+                out[n] = jnp.take(src, ri, axis=0)
+            return out
+    else:
+        # unique build keys (every FK->PK join): the matched probe rows
+        # with their lo, sorted at most once per route (_Selection), so
+        # a re-dispatch at the exact size only slices and gathers
+        names = left.schema.names + right.schema.names
+        sel = _Selection(m, [lo] + [left.columns[n]
+                                    for n in left.schema.names])
+        build_cols = [right.columns[n] for n in right.schema.names]
+
+        def gather(out_cap: int) -> Dict[str, jnp.ndarray]:
+            return dict(zip(names, _join_gather_unique(
+                *sel.sort_for(out_cap), order, out_cap, len(sel.cols),
+                *sel.cols, *build_cols)))
+    ctx.metrics.note_join("expand" if dup else "unique")
 
     est = ctx.estimate("join", (lc, rc), left.nrows, right.nrows)
     if est is not None:
         # bound the speculative gather at a small multiple of the
         # larger input — a runaway NDV-based estimate (e.g. join keys
         # with no stats) must not allocate |L|x|R|-sized arrays; a true
-        # output beyond the bound just takes the overflow re-gather
-        upper = 4 * max(left.nrows, right.nrows, 1)
+        # output beyond the bound just takes the overflow re-gather.
+        # With unique build keys no output exceeds the probe side.
+        upper = (4 * max(left.nrows, right.nrows, 1) if dup
+                 else max(left.nrows, 1))
         cols, total = _deferred_dispatch(ctx, "join", gather, est, upper,
                                          total)
     else:
@@ -993,19 +1108,16 @@ def _exec_sort(node: L.Sort, child: Table, ctx: ExecContext) -> Table:
 def _union_fn(key, names: Tuple[str, ...], l_cap: int, r_cap: int,
               new_cap: int):
     """All union output columns in ONE jitted call: concat live-row
-    masks, O(n) nonzero compaction, every column gathered through the
-    same selection (vs the seed's per-column argsort dispatches)."""
+    masks and columns, one compaction (``_compact_nz_impl``) for every
+    column (vs the seed's per-column argsort dispatches)."""
     k = len(names)
 
     def f(l_nrows, r_nrows, *cols):
         mask = jnp.concatenate([jnp.arange(l_cap) < l_nrows,
                                 jnp.arange(r_cap) < r_nrows])
-        sel = _nonzero_rows(mask, new_cap)
-        outs = []
-        for lc, rc in zip(cols[:k], cols[k:]):
-            merged = jnp.concatenate([lc, rc], axis=0)
-            outs.append(jnp.take(merged, sel, axis=0))
-        return tuple(outs)
+        return _compact_nz_impl(mask, new_cap, *(
+            jnp.concatenate([lc, rc], axis=0)
+            for lc, rc in zip(cols[:k], cols[k:])))
 
     return jax.jit(f)
 
@@ -1129,15 +1241,18 @@ def _try_shard_map_mask(pred: E.Expr, child: Table, ctx: ExecContext):
 # ---------------------------------------------------------------------------
 def _fused_fn(key, pred: E.Expr, in_names: Tuple[str, ...],
               out_cols: Tuple[str, ...], new_cap: int):
-    """mask + count + compact + project as a single jitted function."""
+    """mask + count + compact + project as a single jitted function;
+    the sorted selection comes out too (``keys``, ``carried``), for a
+    re-dispatch at the exact size that does not sort again."""
     def f(nrows, *cols):
         columns = dict(zip(in_names, cols))
         n = cols[0].shape[0]
         mask = E.eval_expr(pred, columns) & (jnp.arange(n) < nrows)
         count = jnp.sum(mask.astype(jnp.int32))
-        sel = _nonzero_rows(mask, new_cap)
-        outs = tuple(jnp.take(columns[c], sel, axis=0) for c in out_cols)
-        return mask, count, outs
+        outs = tuple(columns[c] for c in out_cols)
+        keys, carried = _sort_selection(mask, outs, _carries(n, new_cap))
+        return (mask, count, keys, carried,
+                _head_selected(keys, carried, outs, new_cap))
     return jax.jit(f)
 
 
@@ -1368,17 +1483,15 @@ def _exec_fused(node: FusedPipeline, ctx: ExecContext) -> Table:
         # by the jitted batch oracle instead of the Pallas kernel
         mask, count = _slotted_mask(pred, child, ctx, use_pallas=False)
 
-    def project_compact(new_cap: int):
-        return _compact_nz(mask, new_cap,
-                           *[child.columns[c] for c in node.cols])
-
+    out_cols = [child.columns[c] for c in node.cols]
     if mask is not None:
         if est is not None:
             outs, count = _deferred_dispatch(
-                ctx, "project", project_compact, est, child.capacity, count)
+                ctx, "project", _Selection(mask, out_cols), est,
+                child.capacity, count)
         else:
             count = _host_int(ctx, count)
-            outs = project_compact(next_pow2(max(count, 1)))
+            outs = _compact_nz(mask, next_pow2(max(count, 1)), *out_cols)
     elif est is not None:
         # single dispatch: mask, count and the projected compaction all
         # come out of one jitted call sized by the estimate
@@ -1387,13 +1500,14 @@ def _exec_fused(node: FusedPipeline, ctx: ExecContext) -> Table:
                child.capacity, new_cap)
         fn = _cached(key, lambda: _fused_fn(key, pred, in_names,
                                             node.cols, new_cap))
-        mask, count, outs = fn(jnp.int32(child.nrows), *in_cols)
+        mask, count, keys, carried, outs = fn(jnp.int32(child.nrows),
+                                              *in_cols)
         count = _host_int(ctx, count)
         tight = next_pow2(max(count, 1))
         redispatch = count > new_cap or new_cap > 2 * tight
         if redispatch:
             # estimate overflow (or gross overshoot): recompact exactly
-            outs = project_compact(tight)
+            outs = _Selection(mask, out_cols, keys, carried)(tight)
         ctx.metrics.note_dispatch("project", redispatch)
     else:
         # no estimator: two dispatches, but still no intermediate
@@ -1402,7 +1516,7 @@ def _exec_fused(node: FusedPipeline, ctx: ExecContext) -> Table:
         fn = _cached(key, lambda: _pred_mask_fn(key, pred, in_names))
         mask, count = fn(jnp.int32(child.nrows), *in_cols)
         count = _host_int(ctx, count)
-        outs = project_compact(next_pow2(max(count, 1)))
+        outs = _compact_nz(mask, next_pow2(max(count, 1)), *out_cols)
 
     _pid_record(ctx, pid_scan, pred, mask, child.nrows)
     ctx.metrics.rows_processed += child.nrows
@@ -1557,17 +1671,15 @@ def _finalize_group(members, prep, ctx: ExecContext):
         est = _fused_est(m.src, m.node.pred, child, m.est_rows, ctx)
         mrow = mask[q]
         crow = counts[q]
-
-        def project_compact(new_cap, mrow=mrow, child=child, m=m):
-            return _compact_nz(mrow, new_cap,
-                               *[child.columns[c] for c in m.node.cols])
-
+        out_cols = [child.columns[c] for c in m.node.cols]
         if est is not None:
             cols_out, count = _deferred_dispatch(
-                ctx, "project", project_compact, est, child.capacity, crow)
+                ctx, "project", _Selection(mrow, out_cols), est,
+                child.capacity, crow)
         else:
             count = _host_int(ctx, crow)
-            cols_out = project_compact(next_pow2(max(count, 1)))
+            cols_out = _compact_nz(mrow, next_pow2(max(count, 1)),
+                                   *out_cols)
         _pid_record(ctx, m.pid_scan, m.node.pred, mrow, child.nrows)
         ctx.metrics.rows_processed += child.nrows
         outs.append(Table(m.node.schema,

@@ -182,7 +182,8 @@ class TestJoin:
             (lo_key + rng.integers(-5, width + 5, 500)).clip(
                 -2**31, 2**31 - 1),
             [I32_SENTINEL, -2**31, 2**31 - 1]]).astype(np.int32)
-        order, rks, kmin, kmax = _join_build(jnp.asarray(rk), jnp.int32(60))
+        order, rks, kmin, kmax, _ = _join_build(jnp.asarray(rk),
+                                                jnp.int32(60))
         span = next_pow2(int(kmax) - int(kmin) + 1)
         lo1, m1, t1 = _join_probe(jnp.asarray(lk), rks, jnp.int32(490))
         lo2, m2, t2 = _join_probe_dense(jnp.asarray(lk), rks,
@@ -194,6 +195,69 @@ class TestJoin:
         assert hit.any() and int(t1) == int(t2)
         np.testing.assert_array_equal(np.asarray(lo1)[hit],
                                       np.asarray(lo2)[hit])
+
+    @pytest.mark.parametrize("case", ["fk", "no_matches", "stale_padding"])
+    @pytest.mark.parametrize("carry", [False, True])
+    def test_unique_join_matches_the_expand_path(self, case, carry):
+        # the gather of matched probe rows gives the rows the
+        # jnp.repeat expansion gives, in the same order
+        import jax.numpy as jnp
+
+        from repro.relational.physical import (_join_build, _join_expand,
+                                               _join_gather_unique,
+                                               _join_probe, _select_rows)
+        from repro.relational.schema import next_pow2
+
+        rng = np.random.default_rng(7)
+        nl, nr, lcap, rcap = 211, 97, 256, 128
+        rk = rng.permutation(1000)[:rcap].astype(np.int32)
+        lk = rk[rng.integers(0, rcap, lcap)]     # padding keys too
+        if case == "no_matches":
+            lk = lk + 5000
+        if case == "stale_padding":
+            rk[nr:] = rk[:rcap - nr]             # padding repeats live keys
+        lk, lv = jnp.asarray(lk), jnp.asarray(rng.random(lcap, np.float32))
+        rv = rng.integers(0, 100, rcap).astype(np.int32)
+        order, rks, _, _, dup = _join_build(jnp.asarray(rk), jnp.int32(nr))
+        assert not bool(dup)
+        lo, m, total = _join_probe(lk, rks, jnp.int32(nl))
+        total = int(total)
+        assert (total == 0) == (case == "no_matches")
+        cap = next_pow2(max(total, 1))
+        li, ri = _join_expand(lo, m, cap)
+        want = [jnp.take(lk, li), jnp.take(lv, li),
+                jnp.take(jnp.take(rk, order), ri),
+                jnp.take(jnp.take(rv, order), ri)]
+        keys, carried = _select_rows(m, lo, lk, lv, carry=carry)
+        got = _join_gather_unique(keys, carried, order, cap, 3,
+                                  lo, lk, lv, rk, rv)
+        for a, b in zip(got, want):
+            assert a.dtype == b.dtype
+            np.testing.assert_array_equal(np.asarray(a)[:total],
+                                          np.asarray(b)[:total])
+
+    @pytest.mark.parametrize("dup,path", [(False, "unique"),
+                                          (True, "expand")])
+    def test_join_path_counts(self, dup, path):
+        # build-key uniqueness picks the path; duplicates keep the
+        # jnp.repeat expansion, and both answer as the oracle does
+        from repro.relational import Telemetry
+
+        (stl, lc), (str_, rc), sl, sr = self._two(dup=dup)
+        p = L.scan("l", sl).join(L.scan("r", sr), "a", "b")
+        storages = [(stl, lc), (str_, rc)]
+        ctx = ExecContext(catalog={st.name: st for st, _ in storages})
+        got = execute(p, ctx).row_multiset()
+        assert got and got == _expect(p, storages)
+        assert ctx.metrics.joins == {path: 1}
+        tel = Telemetry()
+        tel.absorb_exec_metrics(ctx.metrics)
+        reg = tel.registry
+        assert reg.value("exec.joins") == 1
+        assert reg.value("exec.joins", labels={"path": path}) == 1
+        assert [(lab["path"], reg.value("exec.joins", labels=lab))
+                for lab, _ in reg.series("exec.joins")] == [
+            ("unique", int(not dup)), ("expand", int(dup))]
 
     def test_join_after_filters_with_stale_padding(self):
         # regression: compaction slack rows must never match (the
@@ -209,20 +273,111 @@ class TestCompaction:
     @pytest.mark.parametrize("n", [5, 1024, 3000, 4096, 1 << 14])
     @pytest.mark.parametrize("density", [0.0, 0.3, 1.0])
     @pytest.mark.parametrize("slack", [-7, 0, 9])
-    def test_nonzero_rows_matches_jnp_nonzero(self, n, density, slack):
-        # sizes below, at and above the selected count; masks shorter
-        # than one tile, whole tiles, and a ragged tail
+    @pytest.mark.parametrize("carry", [False, True])
+    def test_nonzero_rows_matches_jnp_nonzero(self, n, density, slack,
+                                              carry):
+        # the compaction of the row index column is the selected rows,
+        # 0 after them; sizes below, at and above the selected count;
+        # mask lengths that are and are not powers of two
         import jax.numpy as jnp
 
-        from repro.relational.physical import _nonzero_rows
+        from repro.relational.physical import _select_rows, _take_selected
 
         rng = np.random.default_rng(n + int(density * 10))
-        mask = rng.random(n) < density
+        mask = jnp.asarray(rng.random(n) < density)
         size = max(1, int(mask.sum()) + slack)
-        (want,) = jnp.nonzero(jnp.asarray(mask), size=size, fill_value=0)
-        got = _nonzero_rows(jnp.asarray(mask), size)
+        (want,) = jnp.nonzero(mask, size=size, fill_value=0)
+        rows = jnp.arange(n, dtype=jnp.int32)
+        keys, carried = _select_rows(mask, rows, carry=carry)
+        (got,) = _take_selected(keys, carried, size, rows)
         assert got.dtype == jnp.int32
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+    @staticmethod
+    def _scatter_rows(mask, size):
+        """The selection as a scatter of each set row's index to its
+        rank (the form the sort replaced): the reference."""
+        import jax.numpy as jnp
+
+        rank = jnp.where(mask, jnp.cumsum(mask.astype(jnp.int32)) - 1, size)
+        rows = jnp.arange(mask.shape[0], dtype=jnp.int32)
+        return jnp.zeros((size,), jnp.int32).at[rank].set(rows, mode="drop")
+
+    @staticmethod
+    def _mask(n, kind):
+        rng = np.random.default_rng(n)
+        return {"random": rng.random(n) < 0.4, "none": np.zeros(n, bool),
+                "all": np.ones(n, bool)}[kind]
+
+    @pytest.mark.parametrize("n", [5, 1000, 4096, 1 << 14])
+    @pytest.mark.parametrize("kind", ["random", "none", "all"])
+    @pytest.mark.parametrize("size", ["under", "exact", "over", "beyond"])
+    @pytest.mark.parametrize("carry", [False, True])
+    def test_sort_selection_matches_the_scatter_form(self, n, kind, size,
+                                                     carry):
+        # new_cap below and above the selected count, and beyond the
+        # mask's length (a union's capacity can round past it); the
+        # columns gathered through the keys or carried through the sort
+        import jax.numpy as jnp
+
+        from repro.relational.physical import _select_rows, _take_selected
+
+        mask = jnp.asarray(self._mask(n, kind))
+        count = int(mask.sum())
+        cap = {"under": max(1, count // 2), "exact": max(1, count),
+               "over": count + 9, "beyond": n + 3}[size]
+        want = np.asarray(self._scatter_rows(mask, cap))
+        assert want.shape == (cap,) and not want[count:].any()
+        cols = (jnp.arange(n, dtype=jnp.int32),          # the rows
+                jnp.arange(n, dtype=jnp.float32) * 1.5 - 7.0,
+                jnp.arange(4 * n, dtype=jnp.uint8).reshape(n, 4))
+        keys, carried = _select_rows(mask, *cols, carry=carry)
+        assert len(carried) == (2 if carry else 0)
+        for taken, col in zip(_take_selected(keys, carried, cap, *cols),
+                              cols):
+            assert taken.dtype == col.dtype
+            np.testing.assert_array_equal(np.asarray(taken),
+                                          np.asarray(col)[want])
+
+    @pytest.mark.parametrize("fn", ["compact_nz", "fused_fn", "union_fn"])
+    @pytest.mark.parametrize("cap", [8, 64, 1024])
+    def test_compacted_outputs_match_the_scatter_form(self, fn, cap):
+        # every output buffer, fill rows included, is what the scatter
+        # selection gives; caps below and above n/16, where the sort
+        # starts carrying the columns
+        import jax.numpy as jnp
+
+        from repro.relational.physical import (_compact_nz, _fused_fn,
+                                               _union_fn)
+
+        n = 1024
+        rng = np.random.default_rng(cap)
+        x = jnp.asarray(rng.random(n, dtype=np.float32))
+        q = jnp.asarray(rng.integers(0, 100, n).astype(np.int32))
+        if fn == "union_fn":
+            nl, nr = 300, 200
+            mask = jnp.concatenate([jnp.arange(n) < nl, jnp.arange(n) < nr])
+            want = [jnp.take(jnp.concatenate([c, c[::-1]]),
+                             self._scatter_rows(mask, cap))
+                    for c in (x, q)]
+            got = _union_fn(None, ("x", "q"), n, n, cap)(
+                jnp.int32(nl), jnp.int32(nr), x, q, x[::-1], q[::-1])
+        else:
+            mask = (x > 0.7) & (q >= 10) & (jnp.arange(n) < 1000)
+            want = [jnp.take(c, self._scatter_rows(mask, cap))
+                    for c in (x, q)]
+            if fn == "compact_nz":
+                got = _compact_nz(mask, cap, x, q)
+            else:
+                pred = E.and_(E.cmp("x", ">", 0.7), E.cmp("q", ">=", 10))
+                _, count, _, _, got = _fused_fn(None, pred, ("x", "q"),
+                                                ("x", "q"), cap)(
+                    jnp.int32(1000), x, q)
+                assert int(count) == int(mask.sum())
+        for a, b in zip(got, want):
+            assert a.dtype == b.dtype
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 class TestCSVParse:

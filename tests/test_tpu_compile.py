@@ -17,7 +17,8 @@ from repro.kernels.filter_project.kernel import (filter_scan,
                                                  parse_i32)
 from repro.relational import expr as E
 from repro.relational.physical import (_compact_nz, _fused_fn,
-                                       _prefix_count, _sharded_mask_fn)
+                                       _join_gather_unique, _select_rows,
+                                       _sharded_mask_fn, _take_selected)
 
 FACT_CAP = 1 << 25
 DIM_CAP = 128
@@ -99,15 +100,39 @@ def test_fused_xla_mask_compact_compiles(one_chip):
              *_columns(one_chip, FACT_CAP)).compile()
 
 
-def test_prefix_count_compiles(one_chip):
-    jax.jit(_prefix_count).lower(
-        _spec(one_chip, (FACT_CAP,), jnp.bool_)).compile()
+@pytest.mark.parametrize("carry", [False, True])
+def test_selection_compiles(one_chip, carry):
+    # the sort over a filter's mask (gathering the columns after it) or
+    # a join's match counts (carrying them through it), then the
+    # F2-sized output from it
+    cols = _columns(one_chip, FACT_CAP)
+    flags = _spec(one_chip, (FACT_CAP,), jnp.int32 if carry else jnp.bool_)
+    keys, carried = jax.eval_shape(
+        lambda f, *c: _select_rows(f, *c, carry=carry), flags, *cols)
+    _select_rows.lower(flags, *cols, carry=carry).compile()
+    shard = lambda a: _spec(one_chip, a.shape, a.dtype)  # noqa: E731
+    _take_selected.lower(shard(keys), tuple(map(shard, carried)),
+                         FACT_CAP // 2, *cols).compile()
+
+
+def test_unique_join_gather_compiles(one_chip):
+    # the fact side's matched rows into a 2^22 output: lo and two probe
+    # columns carried through the sort, two build columns gathered from
+    # the item dimension's 2,048-row capacity
+    i32 = jnp.int32
+    fact = _spec(one_chip, (FACT_CAP,), i32)
+    _join_gather_unique.lower(
+        fact, (fact,) + _columns(one_chip, FACT_CAP),
+        _spec(one_chip, (2048,), i32), 1 << 22, 3,
+        fact, *_columns(one_chip, FACT_CAP),
+        *_columns(one_chip, 2048)).compile()
 
 
 def test_sharded_scan_programs_compile(topo):
     # the four-chip path's programs at the SF10 fact capacity: per-shard
     # mask under shard_map, then the global compaction of 4 columns to
-    # an F2-sized output, all row-sharded over the 2x2 mesh
+    # an F2-sized output (carried through the sort), all row-sharded
+    # over the 2x2 mesh
     import numpy as np
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
