@@ -12,6 +12,22 @@ program's span tracer, and the result carries the per-layer metrics.
 Everything that belongs to one configuration, traffic mix or metric is
 found by name: ``configs/<name>.json``, ``traffic/<name>.json``,
 ``families/<name>.py`` and ``metrics/<name>.py``.
+
+A configuration file states the deployment and how it was cut to size:
+
+* ``source``: the public definition it follows, down to the scale factor;
+  ``tables.<name>.rows`` the rows each table holds.
+* ``reduced``: every cut of scale from the source (columns left out, rows
+  fewer than the source's), each with its reason.
+* ``assumed``: every size or distribution the source leaves open and the
+  file sets itself.
+* ``tables.<name>.partitioning``: the deployment's physical layout, not a
+  cut: ``{"column", "scheme", "n_partitions"}``, as
+  ``repro.relational.partition.Partitioning`` takes them.  The table is
+  registered horizontally partitioned (rows re-clustered, partition
+  statistics, pruning, partition-grained covering expressions and the
+  partition-bitset pool).  State where the layout comes from beside the
+  file's ``source``.  A table without the key is registered whole.
 """
 from __future__ import annotations
 
@@ -176,6 +192,9 @@ class RunRecord:
     trace_record: Optional[dict] = None       # tracereduce.extract
     peaks: Optional[dict] = None
     device_kind: str = ""
+    # growth of the session's registry counters over the window (traced
+    # runs): name, with its labels, -> count
+    counters: Optional[Dict[str, float]] = None
 
     @property
     def answered(self) -> List[QueryRecord]:
@@ -186,16 +205,22 @@ class RunRecord:
         return max((r.t_done for r in self.answered), default=self.t_start)
 
 
-def register(sess, table: str, catalog: datagen.Catalog) -> None:
-    """Register (or re-register) one table of the catalog, columnar."""
-    from repro.relational import F32, I32, STR, Schema, make_storage
+def register(sess, table: str, catalog: datagen.Catalog,
+             config: dict) -> None:
+    """Register (or re-register) one table of the catalog, columnar, in
+    the layout the configuration states for it."""
+    from repro.relational import (F32, I32, STR, Partitioning, Schema,
+                                  make_storage)
 
     schema = Schema.of(*[
         (n, {"i32": I32, "f32": F32}.get(k) or STR(datagen.str_width(k)))
         for n, k in datagen.COLUMNS[table]])
     st, _ = make_storage(table, schema, datagen.table_rows(catalog, table),
                          "columnar", cols=catalog[table])
-    sess.register(st, columnar_for_stats=catalog[table])
+    layout = config["tables"][table].get("partitioning")
+    sess.register(st, columnar_for_stats=catalog[table],
+                  partitioning=None if layout is None
+                  else Partitioning(**layout))
 
 
 def build_session(config: dict, catalog: datagen.Catalog):
@@ -208,8 +233,36 @@ def build_session(config: dict, catalog: datagen.Catalog):
         execution=ExecutionConfig(use_pallas_filter=bool(s["pallas_filter"])),
         memory=MemoryConfig(budget_bytes=int(s["device_budget_bytes"]))))
     for table in datagen.COLUMNS:
-        register(sess, table, catalog)
+        register(sess, table, catalog, config)
     return sess
+
+
+def reuse_state(sess) -> Dict[str, list]:
+    """The keys of the session's covering-expression (``ce``) and
+    partition-bitset (``pid``) pools: what one query leaves for another
+    to reuse."""
+    return {name: list(pool.keys()) for name, pool in
+            sess.memory.pools.items() if name in ("ce", "pid")}
+
+
+def reset_reuse_state(sess, catalog: datagen.Catalog, config: dict) -> None:
+    """Drop what earlier queries left for later ones, keeping every
+    table's columns on the device.  Re-registering ``date_dim`` drops
+    every covering expression and resident, partition-grained ones too,
+    and ``date_dim``'s own partition bitsets (``Session.register``); the
+    bitsets that scans of the other tables recorded go with the pid
+    pool's entries."""
+    from repro.core.memory import PidPool
+
+    register(sess, "date_dim", catalog, config)
+    pid = sess.memory.pools.get(PidPool.POOL)
+    if pid is not None:
+        pid.clear()
+
+
+def counter_growth(before: dict, after: dict) -> Dict[str, float]:
+    """Each registry counter's growth from one snapshot to a later one."""
+    return {k: v - before.get(k, 0) for k, v in after.items()}
 
 
 def load_families(names) -> Dict[str, object]:
@@ -410,12 +463,12 @@ def run_cell(cell_name: str, seed: int, seconds: float, trace: bool, *,
         replayed = await replay()
         log("setup", phase="replay", seconds=clock() - t, queries=replayed,
             **compiles.since(m))
-        # re-registering a table drops every covering expression and
-        # resident the replay made (Session.register), so the window
-        # starts from the state the replay started from; the fact
-        # table's columns stay on the device
+        # the window starts from the state the replay started from, with
+        # every program compiled and the tables' columns on the device
         t = clock()
-        register(sess, "date_dim", catalog)
+        left = {k: len(v) for k, v in reuse_state(sess).items()}
+        reset_reuse_state(sess, catalog, config)
+        log("setup", phase="reset", seconds=clock() - t, dropped=left)
         svc = service()
         streams = mix.streams()
         try:
@@ -429,6 +482,8 @@ def run_cell(cell_name: str, seed: int, seconds: float, trace: bool, *,
                 shutil.rmtree(TRACE_DIR, ignore_errors=True)
                 jax.profiler.start_trace(str(TRACE_DIR),
                                          profiler_options=_profiler_options())
+            registry = sess.telemetry().registry
+            before = registry.snapshot()["counters"] if trace else None
             mark = compiles.mark()
             pauses = GcPauses()
             summary["t_start"] = t0 = clock()
@@ -440,6 +495,9 @@ def run_cell(cell_name: str, seed: int, seconds: float, trace: bool, *,
                                       clock=clock,
                                       deadline=lambda: t0 + seconds,
                                       annotate=annotate, explain=trace)
+                if trace:
+                    summary["counters"] = counter_growth(
+                        before, registry.snapshot()["counters"])
             finally:
                 if trace:
                     jax.profiler.stop_trace()
@@ -455,7 +513,8 @@ def run_cell(cell_name: str, seed: int, seconds: float, trace: bool, *,
                     window_compiles=summary["window"]["compiles"],
                     plan_spans_s=summary["plan_s"],
                     peaks=load_json(HERE / "peaks.json"),
-                    device_kind=devices[0].device_kind)
+                    device_kind=devices[0].device_kind,
+                    counters=summary.get("counters"))
     stats = devices[0].memory_stats() or {}
     peak = max((d.memory_stats() or {}).get("peak_bytes_in_use", 0)
                for d in devices[:int(cell["chips"])])

@@ -45,6 +45,14 @@ def tiny_config(name="tpcds-sf1"):
     return config
 
 
+def datepart(config):
+    """The configuration with ``store_sales`` range-partitioned on its
+    sale date into 8 partitions."""
+    config["tables"]["store_sales"]["partitioning"] = {
+        "column": "ss_sold_date_sk", "scheme": "range", "n_partitions": 8}
+    return config
+
+
 @pytest.fixture(autouse=True)
 def no_compile_cache(monkeypatch):
     """The harness turns on JAX's persistent cache; a test process keeps
@@ -103,7 +111,7 @@ def _take(streams, steps):
     return [[next(s) for _ in range(steps)] for s in streams]
 
 
-@pytest.mark.parametrize("mix", ["adhoc", "dashboard"])
+@pytest.mark.parametrize("mix", ["adhoc", "dashboard", "dashboard-replay2"])
 def test_traffic_is_the_same_every_time(mix):
     spec = loadgen.load(mix)
     a = _take(loadgen.Mix(spec).streams(), 12)
@@ -224,7 +232,8 @@ def test_bf16_rounding_is_to_nearest_even():
     assert bf16_round(x).tolist() == [1.0, 1.0, 1.015625, -3.0]
 
 
-@pytest.mark.parametrize("cell", ["sf10-adhoc", "sf1-dashboard"])
+@pytest.mark.parametrize("cell", ["sf10-adhoc", "sf1-dashboard",
+                                  "sf10-dashboard"])
 def test_control_is_not_correct(cell):
     bench = harness.load_benchmark()
     w = harness.cell_of(bench, cell)
@@ -238,11 +247,11 @@ def test_control_is_not_correct(cell):
 # ---------------------------------------------------------------------------
 # whole runs on the CPU, sound and with the timed path broken
 # ---------------------------------------------------------------------------
-def _run(cell, hook=None, trace=False, seconds=1.0):
+def _run(cell, hook=None, trace=False, seconds=1.0, config=None):
     import jax
 
     bench = harness.load_benchmark()
-    config = tiny_config(harness.cell_of(bench, cell)["config"])
+    config = config or tiny_config(harness.cell_of(bench, cell)["config"])
     return harness.run_cell(cell, 2**33 + 5, seconds, trace,
                             t_process=time.monotonic(),
                             devices=jax.devices(), bench=bench,
@@ -311,9 +320,146 @@ def _half_left_out(svc):
     svc._finish = half
 
 
-@pytest.mark.parametrize("fault", [_altered, _stale, _half_left_out],
-                         ids=["answer_altered", "state_unchanged",
-                              "half_left_out"])
-def test_broken_timed_path_is_not_correct(fault):
-    res = _run("sf1-dashboard", hook=fault)
+FAULTS = {"answer_altered": _altered, "state_unchanged": _stale,
+          "half_left_out": _half_left_out}
+
+
+@pytest.mark.parametrize(
+    "fault,layout",
+    [pytest.param(f, None, id=name) for name, f in FAULTS.items()]
+    + [pytest.param(f, datepart, id=f"{name}-datepart")
+       for name, f in FAULTS.items()])
+def test_broken_timed_path_is_not_correct(fault, layout):
+    config = tiny_config()
+    res = _run("sf1-dashboard", hook=fault,
+               config=layout(config) if layout else config)
     assert res["correct"] is False, res["checks"]
+
+
+# ---------------------------------------------------------------------------
+# a partitioned table, and the reset between set-up and the window
+# ---------------------------------------------------------------------------
+def _logged(err: str, tag: str, phase: str) -> dict:
+    for line in err.splitlines():
+        if line.startswith(tag + " "):
+            fields = json.loads(line[len(tag) + 1:])
+            if fields.get("phase") == phase:
+                return fields
+    raise AssertionError(f"no {tag} {phase} line")
+
+
+@pytest.mark.parametrize("cell", ["sf10-adhoc", "sf1-dashboard"])
+def test_partitioned_run_is_correct_and_reset_drops_reuse_state(
+        cell, capsys, monkeypatch):
+    """``store_sales`` range-partitioned through the configuration: the
+    answers are correct, and the reset after the replay leaves no
+    covering expression and no partition bitset, with the fact table's
+    partitions still on the device.  Ad-hoc traffic has no warm-up
+    steps, so its window starts from that state; a dashboard's warm-up
+    refresh builds its panels' state again."""
+    seen = {}
+    reset = harness.reset_reuse_state
+
+    def spy(sess, *args):
+        reset(sess, *args)
+        seen["parts"] = sess.catalog["store_sales"].partitions
+        seen.update(harness.reuse_state(sess))
+        seen["scan"] = list(sess.memory.pools["scan"].keys())
+    monkeypatch.setattr(harness, "reset_reuse_state", spy)
+    res = _run(cell, config=datepart(tiny_config()))
+    assert res["correct"], res["checks"]
+    assert res["attempted"] > 0 and res["failed"] == 0
+    assert seen["parts"] is not None and seen["parts"].n_partitions == 8
+    # the replay left covering expressions and bitsets ...
+    dropped = _logged(capsys.readouterr().err, "setup", "reset")["dropped"]
+    assert dropped["ce"] > 0 and dropped["pid"] > 0, dropped
+    # ... the reset drops them and keeps the partitions' columns
+    assert seen["ce"] == [] and seen["pid"] == []
+    assert any(k[0] == "store_sales" and "part" in k for k in seen["scan"])
+
+
+def test_configuration_without_layout_registers_whole(monkeypatch):
+    """A table without ``partitioning`` is registered as before: no
+    partitioning is passed and the table holds no partitions."""
+    from repro.relational import Session
+
+    calls = []
+    register = Session.register
+
+    def spy(self, storage, *args, **kw):
+        calls.append((storage.name, kw.get("partitioning")))
+        return register(self, storage, *args, **kw)
+    monkeypatch.setattr(Session, "register", spy)
+    config = tiny_config()
+    sess = harness.build_session(config, datagen.generate(config, 1))
+    assert calls == [(t, None) for t in datagen.COLUMNS]
+    assert all(sess.catalog[t].partitions is None for t in datagen.COLUMNS)
+    calls.clear()
+    config = datepart(tiny_config())
+    sess = harness.build_session(config, datagen.generate(config, 1))
+    assert [t for t, p in calls if p is not None] == ["store_sales"]
+    assert calls[0][1].column == "ss_sold_date_sk"
+
+
+# ---------------------------------------------------------------------------
+# the window's registry counters and their readers
+# ---------------------------------------------------------------------------
+def _counted_run(**counters):
+    return harness.RunRecord(setup_s=1, t_start=0, records=[
+        harness.QueryRecord("F1", {}, 0.0, 1.0, cols={}) for _ in range(4)],
+        counters=counters)
+
+
+def test_counter_readers_on_handmade_runs():
+    from chipbench.metrics import host_syncs_per_query, redispatch_share
+
+    run = _counted_run(**{"exec.host_syncs": 14,
+                          "exec.deferred_dispatches": 10,
+                          "exec.redispatches": 3,
+                          "exec.redispatches{op=join}": 2})
+    assert host_syncs_per_query.read(run) == pytest.approx(3.5)
+    assert redispatch_share.read(run) == pytest.approx(0.3)
+    # no deferred dispatch: no share; untraced (no counters): nothing
+    assert redispatch_share.read(_counted_run(**{"exec.host_syncs": 2})) \
+        is None
+    untraced = _counted_run()
+    untraced.counters = None
+    assert host_syncs_per_query.read(untraced) is None
+    assert redispatch_share.read(untraced) is None
+    assert harness.counter_growth({"a": 2, "b": 1}, {"a": 5, "b": 1,
+                                                     "c": 4}) == \
+        {"a": 3, "b": 0, "c": 4}
+
+
+def test_window_counters_reach_the_readers_on_a_traced_run(monkeypatch):
+    """A traced run's ``RunRecord.counters`` is the growth of the
+    session's registry counters from the window's start to the end of
+    the run, as ``benchmarks/exec_counters.py`` reads it, and the two
+    readers report it per answer."""
+    runs, kept = [], {}
+    read_metrics = harness.read_metrics
+
+    def keep_run(entries, run):
+        runs.append(run)
+        return read_metrics(entries, run)
+
+    def at_window_start(svc):
+        kept["registry"] = reg = svc.session.telemetry().registry
+        kept["before"] = reg.snapshot()["counters"]
+
+    monkeypatch.setattr(harness, "read_metrics", keep_run)
+    res = _run("sf1-dashboard", hook=at_window_start, trace=True)
+    assert res["correct"], res["checks"]
+    (run,) = runs
+    grown = harness.counter_growth(
+        kept["before"], kept["registry"].snapshot()["counters"])
+    assert run.counters == grown
+    answered = res["attempted"] - res["failed"]
+    assert grown["exec.host_syncs"] > 0
+    assert grown["exec.deferred_dispatches"] > 0
+    metrics = res["metrics"]
+    assert metrics["host_syncs_per_query"]["value"] == pytest.approx(
+        grown["exec.host_syncs"] / answered)
+    assert metrics["redispatch_share"]["value"] == pytest.approx(
+        grown["exec.redispatches"] / grown["exec.deferred_dispatches"])
+    assert metrics["host_syncs_per_query"]["unit"] == "syncs/query"
