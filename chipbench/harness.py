@@ -36,6 +36,7 @@ import contextlib
 import gc
 import importlib
 import json
+import math
 import shutil
 import sys
 import time
@@ -100,7 +101,9 @@ def require_chips(n: int):
 class CompileCounter:
     """Backend compiles and persistent-cache hits, from JAX's monitoring
     events; ``staging_s`` is the seconds spent tracing, lowering and
-    compiling programs in all."""
+    compiling programs in all, loads from the persistent cache included
+    (JAX times a load as a backend compile); ``programs`` names each
+    program compiled or loaded, in order."""
 
     STAGES = ("/jax/core/compile/jaxpr_trace_duration",
               "/jax/core/compile/jaxpr_to_mlir_module_duration",
@@ -113,13 +116,15 @@ class CompileCounter:
         self.compile_s = 0.0
         self.staging_s = 0.0
         self.cache_hits = 0
+        self.programs: List[str] = []
 
-        def on_duration(event, duration, **_):
+        def on_duration(event, duration, fun_name="", **_):
             if event in self.STAGES:
                 self.staging_s += duration
             if event == "/jax/core/compile/backend_compile_duration":
                 self.compiles += 1
                 self.compile_s += duration
+                self.programs.append(fun_name)
 
         def on_event(event, **_):
             if event == "/jax/compilation_cache/cache_hits":
@@ -137,6 +142,9 @@ class CompileCounter:
                 "compile_s": self.compile_s - mark[1],
                 "staging_s": self.staging_s - mark[2],
                 "cache_hits": self.cache_hits - mark[3]}
+
+    def programs_since(self, mark: tuple) -> List[str]:
+        return self.programs[mark[0]:]
 
 
 class GcPauses:
@@ -251,7 +259,14 @@ def reset_reuse_state(sess, catalog: datagen.Catalog, config: dict) -> None:
     every covering expression and resident, partition-grained ones too,
     and ``date_dim``'s own partition bitsets (``Session.register``); the
     bitsets that scans of the other tables recorded go with the pid
-    pool's entries."""
+    pool's entries.
+
+    This is what keeps set-up's promise that the window compiles
+    nothing: the replay, the rehearsal and the window each start from
+    the state this leaves, so a step of the schedule plans the same
+    covering expressions, and runs the same programs, in each of them.
+    What the reset keeps (the tables' device columns, whole or per
+    partition, and the compiled programs) holds no query's answer."""
     from repro.core.memory import PidPool
 
     register(sess, "date_dim", catalog, config)
@@ -277,7 +292,8 @@ async def closed_loop(svc, build, streams, records: List[QueryRecord], *,
     """Clients over ``svc``: each submits a step's queries, awaits each
     answer and brings its columns to the host, then takes the next step.
     A client stops after ``steps`` steps, or once ``deadline()`` has
-    passed and the open window is empty; the queries in flight finish."""
+    passed and the open window is empty; the queries in flight finish.
+    Returns the steps each client took."""
     annotate = annotate or (lambda name: contextlib.nullcontext())
     stop = asyncio.Event()
 
@@ -316,6 +332,7 @@ async def closed_loop(svc, build, streams, records: List[QueryRecord], *,
         # a client that stops closes the open window, so nobody waits on
         # a window the stopped clients would have filled
         await svc.flush()
+        return n
 
     async def timer():
         while (left := deadline() - clock()) > 0:
@@ -326,13 +343,14 @@ async def closed_loop(svc, build, streams, records: List[QueryRecord], *,
     timer_task = (asyncio.create_task(timer()) if deadline is not None
                   else None)
     try:
-        await asyncio.gather(*tasks)
+        served = await asyncio.gather(*tasks)
     finally:
         if timer_task is not None:
             timer_task.cancel()
             await asyncio.gather(timer_task, return_exceptions=True)
         await svc.flush()
         await svc.drain()
+    return list(served)
 
 
 def _plan_span_seconds(sess, t0: float, t1: float) -> Optional[float]:
@@ -430,45 +448,103 @@ def run_cell(cell_name: str, seed: int, seconds: float, trace: bool, *,
         return AsyncQueryService(sess, config=AsyncConfig(
             max_batch=n_batch))
 
-    async def loop(svc, streams, records, **kw):
-        await closed_loop(svc, build, streams, records, clock=clock, **kw)
+    async def loop(svc, streams, records, **kw) -> int:
+        """``closed_loop`` for set-up: a failed query is an error.
+        Returns the most steps a client took."""
+        served = await closed_loop(svc, build, streams, records,
+                                   clock=clock, **kw)
         failed = [r.error for r in records if r.error]
         if failed:
             raise RuntimeError(f"set-up queries failed: {failed[:3]}")
+        return max(served, default=0)
 
-    async def replay():
+    def reset(phase: str) -> None:
+        t = clock()
+        left = {k: len(v) for k, v in reuse_state(sess).items()}
+        reset_reuse_state(sess, catalog, config)
+        log("setup", phase=phase, seconds=clock() - t, dropped=left)
+
+    async def replay() -> dict:
         """The window's own schedule, on its own service, from the
-        state the window starts from: it compiles every program the
-        window will run.  A periodic schedule (dashboards) runs
-        ``replay_steps`` steps; an ad-hoc one runs as long as the window
+        state the window starts from: it compiles every program of the
+        steps it serves.  A periodic schedule (dashboards) runs
+        ``replay_steps`` steps.  An ad-hoc one runs as long as the window
         will, plus the seconds spent tracing, compiling or loading
-        programs, and stops on whole MQO windows, as the window does."""
+        programs, and stops on whole MQO windows, as the window does;
+        it cannot see the other costs of a program's first run, so a
+        window can get further than it did (``rehearse``)."""
         svc = service()
         done: List[QueryRecord] = []
         streams = mix.streams()
         try:
             await loop(svc, streams, done, steps=warm_steps)
             if "replay_steps" in spec:
-                await loop(svc, streams, done, steps=int(spec["replay_steps"]))
+                steps = await loop(svc, streams, done,
+                                   steps=int(spec["replay_steps"]))
             else:
                 end, c0 = clock() + seconds, compiles.staging_s
-                await loop(svc, streams, done,
-                           deadline=lambda: end + compiles.staging_s - c0)
+                steps = await loop(
+                    svc, streams, done,
+                    deadline=lambda: end + compiles.staging_s - c0)
         finally:
             await svc.aclose()
-        return len(done)
+        return {"queries": len(done), "steps": warm_steps + steps}
+
+    async def rehearse(covered: int) -> dict:
+        """The window once more, after the replay and a reset, so that
+        no step the window serves is a first run.  It runs by time at
+        the window's own pace over the ``covered`` steps the replay
+        compiled, and stops on whole MQO windows.  The pace of those
+        compiled steps says how many the window will serve; the
+        rehearsal then goes on to that many and ``rehearse_margin`` more,
+        compiling any step not yet covered.  Where the compiled steps
+        were under half of that, their pace says too little: it resets
+        and runs again, at most three times in all."""
+        per_step = spec["clients"] * mix.queries_per_step()
+        margin = float(spec["rehearse_margin"])
+        passes = []
+        while True:
+            svc = service()
+            done: List[QueryRecord] = []
+            streams = mix.streams()
+            try:
+                await loop(svc, streams, done, steps=warm_steps)
+                gc.collect()        # the replay's garbage, not the pace's
+                t0, first = clock(), len(done)
+                reach = await loop(svc, streams, done,
+                                   deadline=lambda: t0 + seconds)
+                # the compiled steps, and when they were done
+                warm = min(reach, covered)
+                t_warm = sorted(r.t_done for r in done[first:])[
+                    warm * per_step - 1] - t0
+                pace = max(reach, warm * seconds / t_warm)
+                target = math.ceil(pace * (1 + margin))
+                if target > reach:
+                    await loop(svc, streams, done, steps=target - reach)
+            finally:
+                await svc.aclose()
+            covered = max(covered, target)
+            passes.append({"reach": warm_steps + reach, "warm": warm,
+                           "warm_s": t_warm, "steps": warm_steps + target})
+            if 2 * warm >= pace or len(passes) == 3:
+                return {"steps": warm_steps + covered, "passes": passes}
+            reset("reset")
 
     async def serve():
         t, m = clock(), compiles.mark()
         replayed = await replay()
-        log("setup", phase="replay", seconds=clock() - t, queries=replayed,
+        log("setup", phase="replay", seconds=clock() - t, **replayed,
             **compiles.since(m))
         # the window starts from the state the replay started from, with
         # every program compiled and the tables' columns on the device
+        reset("reset")
+        if "rehearse_margin" in spec:
+            t, r = clock(), compiles.mark()
+            rehearsed = await rehearse(replayed["steps"] - warm_steps)
+            log("setup", phase="rehearsal", seconds=clock() - t,
+                **rehearsed, **compiles.since(r))
+            reset("reset")
         t = clock()
-        left = {k: len(v) for k, v in reuse_state(sess).items()}
-        reset_reuse_state(sess, catalog, config)
-        log("setup", phase="reset", seconds=clock() - t, dropped=left)
         svc = service()
         streams = mix.streams()
         try:
@@ -491,10 +567,10 @@ def run_cell(cell_name: str, seed: int, seconds: float, trace: bool, *,
             try:
                 with (annotate("bench.window") if trace
                       else contextlib.nullcontext()):
-                    await closed_loop(svc, build, streams, window_records,
-                                      clock=clock,
-                                      deadline=lambda: t0 + seconds,
-                                      annotate=annotate, explain=trace)
+                    served = await closed_loop(
+                        svc, build, streams, window_records, clock=clock,
+                        deadline=lambda: t0 + seconds, annotate=annotate,
+                        explain=trace)
                 if trace:
                     summary["counters"] = counter_growth(
                         before, registry.snapshot()["counters"])
@@ -502,6 +578,8 @@ def run_cell(cell_name: str, seed: int, seconds: float, trace: bool, *,
                 if trace:
                     jax.profiler.stop_trace()
             summary["window"] = compiles.since(mark)
+            summary["window"]["steps"] = warm_steps + max(served, default=0)
+            summary["window"]["programs"] = compiles.programs_since(mark)
             summary["host"] = pauses.close()
             summary["plan_s"] = _plan_span_seconds(sess, t0, clock())
         finally:

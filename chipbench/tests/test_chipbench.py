@@ -111,7 +111,8 @@ def _take(streams, steps):
     return [[next(s) for _ in range(steps)] for s in streams]
 
 
-@pytest.mark.parametrize("mix", ["adhoc", "dashboard", "dashboard-replay2"])
+@pytest.mark.parametrize("mix", ["adhoc", "dashboard", "dashboard-replay2",
+                                 "adhoc-rehearsed", "adhoc-dated"])
 def test_traffic_is_the_same_every_time(mix):
     spec = loadgen.load(mix)
     a = _take(loadgen.Mix(spec).streams(), 12)
@@ -130,6 +131,23 @@ def test_adhoc_literals_are_fresh_and_cycle_families():
     seen = [json.dumps(q, sort_keys=True) for client in steps
             for g in client for q in g]
     assert len(seen) == len(set(seen))      # no literal repeats
+
+
+def test_dated_adhoc_windows_hold_four_of_each_family():
+    """``adhoc-dated``: 8 clients alternate F1 and F6, so every window of
+    8 holds 4 yearly and 4 monthly slices, and no literal repeats until a
+    family's pool (50 and 60 slices) is used up."""
+    spec = loadgen.load("adhoc-dated")
+    assert spec["cycle"] == ["F1", "F6"]
+    mix = loadgen.Mix(spec)
+    assert len(mix.pools["F1"]) == 50 and len(mix.pools["F6"]) == 60
+    steps = _take(mix.streams(), 6)
+    for k in range(6):
+        fams = [client[k][0][0] for client in steps]
+        assert sorted(fams) == ["F1"] * 4 + ["F6"] * 4
+    seen = [json.dumps(q, sort_keys=True) for client in steps
+            for g in client for q in g]
+    assert len(seen) == len(set(seen))
 
 
 def test_dashboard_panels_repeat_per_client():
@@ -232,16 +250,20 @@ def test_bf16_rounding_is_to_nearest_even():
     assert bf16_round(x).tolist() == [1.0, 1.0, 1.015625, -3.0]
 
 
-@pytest.mark.parametrize("cell", ["sf10-adhoc", "sf1-dashboard",
-                                  "sf10-dashboard"])
-def test_control_is_not_correct(cell):
+@pytest.mark.parametrize("cell,fails", [
+    pytest.param(cell, fails, id=cell) for cell, fails in [
+        ("sf10-adhoc", "mismatched_answers"),
+        ("sf1-dashboard", "mismatched_answers"),
+        ("sf10-dashboard", "mismatched_answers"),
+        ("sf1-adhoc", "mismatched_answers")]])
+def test_control_is_not_correct(cell, fails):
     bench = harness.load_benchmark()
     w = harness.cell_of(bench, cell)
     config = tiny_config(w["config"])
     numbers = control.control_numbers(config, loadgen.load(w["traffic"]),
                                       5, 16)
     assert not check.verdict(numbers, config["limits"])
-    assert numbers["mismatched_answers"] > 0
+    assert numbers[fails] > config["limits"][fails]
 
 
 # ---------------------------------------------------------------------------
@@ -325,15 +347,117 @@ FAULTS = {"answer_altered": _altered, "state_unchanged": _stale,
 
 
 @pytest.mark.parametrize(
-    "fault,layout",
-    [pytest.param(f, None, id=name) for name, f in FAULTS.items()]
-    + [pytest.param(f, datepart, id=f"{name}-datepart")
+    "fault,layout,cell",
+    [pytest.param(f, None, "sf1-dashboard", id=name)
+     for name, f in FAULTS.items()]
+    + [pytest.param(f, datepart, "sf1-dashboard", id=f"{name}-datepart")
+       for name, f in FAULTS.items()]
+    # ad-hoc traffic over date partitions, rehearsal and all
+    + [pytest.param(f, datepart, "sf1-adhoc", id=f"{name}-adhoc-rehearsed")
        for name, f in FAULTS.items()])
-def test_broken_timed_path_is_not_correct(fault, layout):
-    config = tiny_config()
-    res = _run("sf1-dashboard", hook=fault,
+def test_broken_timed_path_is_not_correct(fault, layout, cell):
+    bench = harness.load_benchmark()
+    config = tiny_config(harness.cell_of(bench, cell)["config"])
+    res = _run(cell, hook=fault,
                config=layout(config) if layout else config)
     assert res["correct"] is False, res["checks"]
+
+
+# ---------------------------------------------------------------------------
+# set-up's promise: the window compiles nothing
+# ---------------------------------------------------------------------------
+def _window_line(err: str) -> dict:
+    (line,) = [ln for ln in err.splitlines() if ln.startswith("window ")]
+    return json.loads(line[len("window "):])
+
+
+def _slow_first_runs(delay_s: float) -> None:
+    """Every query's first run in the process takes ``delay_s`` more, as
+    a first run on the chip pays allocation and layout work that no
+    compile counter sees: the replay serves every query for the first
+    time, a warm window none."""
+    seen = set()
+    load = harness.load_families
+
+    def slowed(names):
+        out = {}
+        for name, mod in load(names).items():
+            def build(tables, c, params, _build=mod.build, _name=name):
+                key = (_name, json.dumps(params, sort_keys=True))
+                if key not in seen:
+                    seen.add(key)
+                    time.sleep(delay_s)
+                return _build(tables, c, params)
+            out[name] = type(mod)(mod.__name__)
+            out[name].__dict__.update(vars(mod), build=build)
+        return out
+    harness.load_families = slowed
+
+
+def _fresh_child(cell: str, partitioned: bool, slow_s: float,
+                 rehearse: bool) -> None:
+    """The child of ``_fresh_run``: one run, its result on stdout."""
+    import repro.launch.compile_cache as cc
+
+    cc.enable_compile_cache = lambda: "off"
+    if slow_s:
+        _slow_first_runs(slow_s)
+    if not rehearse:
+        load = loadgen.load
+        loadgen.load = lambda name: {k: v for k, v in load(name).items()
+                                     if k != "rehearse_margin"}
+    config = tiny_config(harness.cell_of(harness.load_benchmark(),
+                                         cell)["config"])
+    print(json.dumps(_run(cell, seconds=2.0, config=datepart(config)
+                          if partitioned else config)))
+
+
+def _fresh_run(cell: str, partitioned=False, slow_s=0.0, rehearse=True):
+    """``run_cell`` in a process of its own, where no program is compiled
+    yet: this one has compiled the same queries in earlier tests.
+    Returns the result line and the run's log."""
+    code = (f"import sys; sys.path[:0] = {[str(HERE), str(ROOT)]!r}; "
+            f"import test_chipbench as t; t._fresh_child({cell!r}, "
+            f"{partitioned!r}, {slow_s!r}, {rehearse!r})")
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               PYTHONPATH=os.pathsep.join([str(ROOT / "src"), str(ROOT)]))
+    proc = subprocess.run([sys.executable, "-c", code], cwd=ROOT, env=env,
+                          capture_output=True, text=True, timeout=600)
+    assert proc.returncode == 0, proc.stderr[-4000:]
+    return json.loads(proc.stdout.splitlines()[-1]), proc.stderr
+
+
+@pytest.mark.parametrize("cell,partitioned", [
+    pytest.param("sf1-adhoc", False, id="sf1-adhoc"),
+    pytest.param("sf1-adhoc", True, id="sf1-adhoc-datepart")])
+def test_adhoc_window_compiles_nothing(cell, partitioned):
+    """Ad-hoc traffic with fresh literals compiles programs at new
+    steps.  On the CPU a program's first run costs far more than the
+    seconds spent compiling it, so the replay by time falls short of the
+    window by a third or more: the rehearsal has to cover the rest, with
+    ``store_sales`` whole and partitioned."""
+    res, err = _fresh_run(cell, partitioned=partitioned)
+    assert res["correct"], res["checks"]
+    window = _window_line(err)
+    assert window["compiles"] == 0 and window["cache_hits"] == 0, err
+    assert window["programs"] == []
+    assert _logged(err, "setup", "rehearsal")["steps"] > 0
+
+
+@pytest.mark.parametrize("rehearse", [True, False],
+                         ids=["rehearsed", "replay-only"])
+def test_slowed_first_runs_do_not_reach_the_window(rehearse):
+    """The replay's first runs slowed (10 ms a query, some 80 ms a step
+    where a warm step takes about half that): without the rehearsal the
+    window outruns the replay and compiles; with it the window compiles
+    nothing."""
+    res, err = _fresh_run("sf1-adhoc", slow_s=0.010, rehearse=rehearse)
+    assert res["correct"], res["checks"]
+    window = _window_line(err)
+    if rehearse:
+        assert window["compiles"] == 0 and window["cache_hits"] == 0, err
+    else:
+        assert window["compiles"] > 0, err
 
 
 # ---------------------------------------------------------------------------
